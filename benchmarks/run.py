@@ -59,6 +59,9 @@ class _Tee(io.TextIOBase):
 
 
 def main() -> None:
+    from repro.runtime.compile_cache import use_compile_cache
+
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=",".join(SUITES),
                     help="comma-separated subset of: " + ",".join(SUITES))

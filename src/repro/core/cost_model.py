@@ -239,14 +239,19 @@ def pallas_topk_demote_advised(k_sel: int) -> bool:
 # Device latency model for the fused megakernel (kernels/fused_query.py).
 #
 # The block-shape chooser in kernels/ops.py asks this hook to rank the
-# VMEM-feasible (block_q, block_b) candidates.  The constants are v5e-ish
-# and deliberately coarse: the model only needs to order shapes, and the
-# hot path is so memory-bound that the HBM term dominates every ranking.
+# VMEM-feasible (block_q, block_b) candidates.  The rates are the chip's
+# (runtime/roofline.CHIP_PEAKS) and the model is deliberately coarse: it
+# only needs to order shapes, and the hot path is so memory-bound that the
+# HBM term dominates every ranking.
 # ---------------------------------------------------------------------------
 
-HBM_GBPS = 819.0          # v5e HBM bandwidth
-MXU_TFLOPS = 197.0        # v5e bf16/f32-accumulate peak
-VPU_GOPS = 4.0e3          # vector unit, elementwise ops
+
+def _times(bytes_hbm: float, flops_mxu: float, ops_vpu: float):
+    from ..runtime.roofline import local_peaks
+
+    peaks = local_peaks()
+    return (bytes_hbm / peaks.hbm_bw,
+            flops_mxu / peaks.flops + ops_vpu / peaks.vpu_ops)
 
 
 def fused_pass_estimate(Q: int, B: int, n: int, levels, alphabet: int,
@@ -273,8 +278,7 @@ def fused_pass_estimate(Q: int, B: int, n: int, levels, alphabet: int,
     bytes_hbm += Qp * (2 * nb * k if k else 2 * Bp) * 4
     flops_mxu = 2.0 * Qp * Bp * n                     # the verify matmul
     ops_vpu = float(Qp * Bp) * (sum(levels) * (alphabet + 2) + 8)
-    t_mem = bytes_hbm / (HBM_GBPS * 1e9)
-    t_compute = flops_mxu / (MXU_TFLOPS * 1e12) + ops_vpu / (VPU_GOPS * 1e9)
+    t_mem, t_compute = _times(bytes_hbm, flops_mxu, ops_vpu)
     return dict(bytes_hbm=float(bytes_hbm), flops_mxu=flops_mxu,
                 ops_vpu=ops_vpu, t_mem_s=t_mem, t_compute_s=t_compute,
                 t_est_s=max(t_mem, t_compute))
@@ -311,8 +315,7 @@ def subseq_pass_estimate(Q: int, n_windows: int, window: int, stride: int,
     flops_mxu = 2.0 * Qp * Wp * window                 # the verify matmul
     ops_vpu = float(Qp * Wp) * (sum(levels) * (alphabet + 2) + 8)
     ops_vpu += float(Wp) * window * 2                  # in-VMEM z build
-    t_mem = bytes_stream / (HBM_GBPS * 1e9)
-    t_compute = flops_mxu / (MXU_TFLOPS * 1e12) + ops_vpu / (VPU_GOPS * 1e9)
+    t_mem, t_compute = _times(bytes_stream, flops_mxu, ops_vpu)
     return dict(bytes_hbm=float(bytes_stream),
                 bytes_hbm_materialized=float(bytes_mat),
                 hbm_read_ratio=float(bytes_mat) / float(bytes_stream),

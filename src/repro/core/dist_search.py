@@ -21,7 +21,6 @@ from __future__ import annotations
 
 import collections
 import dataclasses
-import functools
 import statistics
 import time
 from concurrent import futures as _futures
@@ -31,12 +30,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from . import representation as repr_registry
 from .engine import (_KNN_SEED_SAMPLE, _SEED_EPS_MAX, DeviceIndex,
                      QuantizedDeviceIndex, QueryReprDev, _compact_mask,
-                     _eps_qcol, _sample_eps, _slacked, _verify_tier,
+                     _eps_qcol, _kth_smallest, _sample_eps,
+                     _screen_upper_bounds, _slacked, _tighten_keep,
+                     _verify_tier,
                      build_device_index, cascade_mask, cascade_trace,
                      compact_answers, knn_query, knn_query_pallas,
                      mixed_query, mixed_query_pallas, quantized_mixed_query,
@@ -127,9 +127,9 @@ def distributed_build(
     out_specs = (P(axis, None), P(axis),
                  tuple(P(axis) for _ in levels),
                  tuple(P(axis, None) for _ in levels), ex_ix)
-    built = shard_map(
+    built = jax.shard_map(
         build_local, mesh=mesh,
-        in_specs=P(axis, None), out_specs=out_specs, check_rep=False,
+        in_specs=P(axis, None), out_specs=out_specs, check_vma=False,
     )(jnp.asarray(series, dtype=jnp.float32))
     s, norms, residuals, words, extra = built
     return DeviceIndex(series=s, norms_sq=norms, words=words,
@@ -200,9 +200,9 @@ def distributed_range_query(
                 tuple(P(axis, None) for _ in levels), ex_ix,
                 P(), (P(),) * len(levels), (P(),) * len(levels), ex_q, P())
     out_specs = (P(None, axis), P(None, axis), P(None, axis), P(None, axis))
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
+        check_vma=False,
     )(index.series, index.norms_sq, index.residuals, index.words, index.extra,
       qr.q, qr.words, qr.residuals, qr.extra, eps)
 
@@ -332,9 +332,9 @@ def distributed_mixed_query(
                 P(), (P(),) * len(levels), (P(),) * len(levels), ex_q,
                 P(), P())
     out_specs = (P(None, axis), P(None, axis), P(None, axis), P(None, axis))
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
+        check_vma=False,
     )(index.series, index.norms_sq, index.residuals, index.words, index.extra,
       qr.q, qr.words, qr.residuals, qr.extra, eps, knn_mask)
 
@@ -472,9 +472,9 @@ def distributed_knn_query(
                 tuple(P(axis, None) for _ in levels), ex_ix,
                 P(), (P(),) * len(levels), (P(),) * len(levels), ex_q)
     out_specs = (P(None, axis), P(None, axis), P(None, axis))
-    gidx, d2, certs = shard_map(
+    gidx, d2, certs = jax.shard_map(
         local, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
+        check_vma=False,
     )(index.series, index.norms_sq, index.residuals, index.words, index.extra,
       qr.q, qr.words, qr.residuals, qr.extra)
 
@@ -519,8 +519,8 @@ def distributed_survivor_count(
                 tuple(P(axis) for _ in levels),
                 tuple(P(axis, None) for _ in levels), ex_ix,
                 P(), (P(),) * len(levels), (P(),) * len(levels), ex_q, P())
-    return shard_map(
-        local, mesh=mesh, in_specs=in_specs, out_specs=P(), check_rep=False,
+    return jax.shard_map(
+        local, mesh=mesh, in_specs=in_specs, out_specs=P(), check_vma=False,
     )(index.series, index.norms_sq, index.residuals, index.words, index.extra,
       qr.q, qr.words, qr.residuals, qr.extra, eps)
 
@@ -575,8 +575,8 @@ def distributed_cascade_trace(
                 tuple(P(axis) for _ in levels),
                 tuple(P(axis, None) for _ in levels), ex_ix,
                 P(), (P(),) * len(levels), (P(),) * len(levels), ex_q, P())
-    return shard_map(
-        local, mesh=mesh, in_specs=in_specs, out_specs=P(), check_rep=False,
+    return jax.shard_map(
+        local, mesh=mesh, in_specs=in_specs, out_specs=P(), check_vma=False,
     )(index.series, index.norms_sq, index.residuals, index.words, index.extra,
       qr.q, qr.words, qr.residuals, qr.extra, eps)
 
@@ -749,9 +749,9 @@ def distributed_subseq_index(
     out_specs = (P(axis, None), P(axis),
                  tuple(P(axis) for _ in levels),
                  tuple(P(axis, None) for _ in levels), ex_ix)
-    series, norms, residuals, words, extra = shard_map(
+    series, norms, residuals, words, extra = jax.shard_map(
         local, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
+        check_vma=False,
     )(jnp.asarray(streams_p, jnp.float32), jnp.asarray(mu_p, jnp.float32),
       jnp.asarray(sd_p, jnp.float32), tuple(jnp.asarray(r) for r in res_p),
       tuple(jnp.asarray(w) for w in words_p),
@@ -1007,41 +1007,54 @@ def _replicated_specs(tree):
 
 
 def _dist_quantized_screen(dti: DistTieredIndex, qr, eps_col,
-                           mesh: Mesh, axis: str, capacity: int):
+                           mesh: Mesh, axis: str, capacity: int,
+                           knn_col=None, k: int = 0):
     """One shard_map round of the quantized screen: every shard runs the
     widened screen on its own resident columns (``engine.quantized_screen``
     — the same jitted oracle as the single-host tier, so the kept set is
     identical by construction) and compacts survivors into a
-    ``capacity``-slot (global id, valid) buffer.  Returns
+    ``capacity``-slot (global id, valid) buffer.  With ``k`` the k-NN rows
+    (``knn_col``) then shrink their radius to the k-th smallest screen
+    upper bound over the whole mesh — each shard's k smallest bounds are
+    all-gathered, k values per query per shard — before compaction (the
+    distributed twin of ``engine._tighten_tiered_keep``).  Returns
     ``(gidx (Q, P·C), valid (Q, P·C), overflow (Q, P))`` — the only
-    arrays that cross shards.
+    arrays that cross shards besides those bounds.
     """
     qdev = dti.dev
     b_loc = dti.size // mesh.shape[axis]
     cap = int(capacity)
     children, aux = qdev.tree_flatten()
     qleaves = (qr.q, qr.words, qr.residuals, qr.extra)
+    if knn_col is None:
+        knn_col = jnp.zeros((qr.q.shape[0], 1), bool)
 
-    def local(ix_children, ql, eps_):
+    def local(ix_children, ql, eps_, knn_):
         lq = QuantizedDeviceIndex.tree_unflatten(aux, ix_children)
         lqr = QueryReprDev(q=ql[0], words=ql[1], residuals=ql[2],
                            extra=ql[3])
-        keep, _ = quantized_screen(lq, lqr, eps_)
+        keep, d2hat = quantized_screen(lq, lqr, eps_)
+        if k:
+            ub = _screen_upper_bounds(lq, lqr.q, d2hat)
+            mine = -jax.lax.top_k(-ub, min(k, b_loc))[0]
+            pool = jax.lax.all_gather(mine, axis, axis=1, tiled=True)
+            keep = _tighten_keep(lq, keep, d2hat, eps_,
+                                 _slacked(_kth_smallest(pool, k)), knn_)
         idx, valid, overflow = _compact_mask(keep, cap)
         gidx = idx + jax.lax.axis_index(axis) * b_loc
         return gidx, valid, overflow[:, None]
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(_shard_tree_specs(children, axis),
-                  _replicated_specs(qleaves), P()),
+                  _replicated_specs(qleaves), P(), P()),
         out_specs=(P(None, axis), P(None, axis), P(None, axis)),
-        check_rep=False,
-    )(children, qleaves, eps_col)
+        check_vma=False,
+    )(children, qleaves, eps_col, knn_col)
 
 
 def _dist_quant_candidates(dti, qr, eps_col, mesh, axis, opts,
-                           cap0: int):
+                           cap0: int, knn_col=None, k: int = 0):
     """Escalating screen rounds: re-run with 4× per-shard capacity while
     any shard overflows, capped at the shard size where compaction cannot
     overflow — so the certificate is always exact on return."""
@@ -1049,7 +1062,7 @@ def _dist_quant_candidates(dti, qr, eps_col, mesh, axis, opts,
     cap = min(b_loc, max(1, int(cap0)))
     for _ in range(opts.max_doublings + 1):
         gidx, valid, overflow = _dist_quantized_screen(
-            dti, qr, eps_col, mesh, axis, cap)
+            dti, qr, eps_col, mesh, axis, cap, knn_col, k)
         if cap >= b_loc or not bool(np.asarray(overflow).any()):
             break
         cap = min(b_loc, cap * 4)
@@ -1140,7 +1153,8 @@ def distributed_quantized_knn_query(
     eps = _dist_seed_eps(dti, qr, k_eff)                     # (Q, 1)
     cap0 = max(4 * k_eff, 64) if opts.capacity is None else int(opts.capacity)
     gidx, valid, overflow = _dist_quant_candidates(
-        dti, qr, _slacked(eps), mesh, axis, opts, max(cap0, k_eff))
+        dti, qr, _slacked(eps), mesh, axis, opts, max(cap0, k_eff),
+        jnp.ones((qr.q.shape[0], 1), bool), k_eff)
     d2 = _verify_tier(dti.raw, gidx, qr.q, valid, opts)
     neg, pos = jax.lax.top_k(-d2, k_eff)                     # ascending d2
     nn_d2 = -neg
@@ -1184,8 +1198,9 @@ def distributed_quantized_mixed_query(
     eps = jnp.where(knn_col, _slacked(_dist_seed_eps(dti, qr, k_eff)),
                     eps_req)
     cap0 = max(4 * k_eff, 64) if opts.capacity is None else int(opts.capacity)
+    k_tight = k_eff if np.asarray(is_knn).any() else 0   # 0: range only
     gidx, valid, overflow = _dist_quant_candidates(
-        dti, qr, eps, mesh, axis, opts, max(cap0, k_eff))
+        dti, qr, eps, mesh, axis, opts, max(cap0, k_eff), knn_col, k_tight)
     d2 = _verify_tier(dti.raw, gidx, qr.q, valid, opts)
     answer = jnp.where(knn_col, valid, valid & (d2 <= eps_req * eps_req))
     gidx = jnp.where(answer, gidx, -1)

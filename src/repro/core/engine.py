@@ -44,6 +44,13 @@ from .representation import DEFAULT_STACK
 from .sax import discretize
 
 
+# Precision of every distance matmul.  A float32 matmul at default
+# precision runs as one bf16 pass on the TPU — about one unit of d² off
+# for z-normalised rows at n=256 — which would break the exact answer
+# sets, the k-NN certificates and the quantized screen's 1e-6 slack.
+_F32 = jax.lax.Precision.HIGHEST
+
+
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
 class DeviceIndex:
@@ -314,9 +321,10 @@ def cascade_mask(
 def verify_distances(
     index: DeviceIndex, qr: QueryReprDev
 ) -> jnp.ndarray:
-    """Squared Euclidean distances (Q, B) via the matmul form (MXU work)."""
+    """Squared Euclidean distances (Q, B) via the matmul form (MXU work),
+    at full f32 precision (see :data:`_F32`)."""
     qn = jnp.sum(qr.q * qr.q, axis=-1)
-    cross = qr.q @ index.series.T  # (Q, B)
+    cross = jnp.dot(qr.q, index.series.T, precision=_F32)  # (Q, B)
     d2 = qn[:, None] - 2.0 * cross + index.norms_sq[None, :]
     return jnp.maximum(d2, 0.0)
 
@@ -810,17 +818,25 @@ def mixed_query_auto(
 # ---------------------------------------------------------------------------
 
 
-def resolve_backend(backend: str = "auto") -> str:
-    """Map auto|xla|pallas to the concrete engine for this process."""
+def resolve_backend(backend: str = "auto", streaming: bool = False) -> str:
+    """Map auto|xla|pallas to the concrete engine for this process.
+
+    ``auto`` is compiled Pallas on a TPU and XLA elsewhere, with one
+    exception: ``streaming=True`` (the streaming subsequence kernels of
+    ``kernels/fused_query.py``, which build their windows in VMEM) is XLA
+    on every platform.  Mosaic cannot lower that window build — strided
+    lane slices of a segment stacked into columns — so those kernels run
+    only in interpret mode, when asked for by name (ROADMAP, Reach 4)."""
     if backend not in ("auto", "xla", "pallas"):
         raise ValueError(
             f"backend must be 'auto', 'xla' or 'pallas', got {backend!r}")
     if backend == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "xla"
+        return ("pallas" if jax.default_backend() == "tpu" and not streaming
+                else "xla")
     return backend
 
 
-def resolve_knn_backend(backend: str, k: int) -> str:
+def resolve_knn_backend(backend: str, k: int, streaming: bool = False) -> str:
     """:func:`resolve_backend` plus the top-k unroll demotion (DESIGN.md
     §7): the fused k-NN kernel unrolls ``k + _TOPK_GUARD`` min/argmin
     sweeps per database block, so its code size and compile time grow
@@ -830,31 +846,36 @@ def resolve_knn_backend(backend: str, k: int) -> str:
     demoted to the XLA engine instead of compiling an ever-longer kernel.
     Demotion never changes answers — both backends are exact — and
     :func:`knn_query_pallas` stays directly callable at any k for
-    callers that want the kernel regardless."""
-    be = resolve_backend(backend)
+    callers that want the kernel regardless.  ``streaming`` is
+    :func:`resolve_backend`'s."""
+    be = resolve_backend(backend, streaming)
     if be == "pallas" and _cost_model.pallas_topk_demote_advised(
             int(k) + _TOPK_GUARD):
         return "xla"
     return be
 
 
-def _fused_blocks(index: DeviceIndex, Q: int, k: int = 0,
-                  block_q: int | None = None, block_b: int | None = None):
+def _fused_blocks(index, Q: int, k: int = 0,
+                  block_q: int | None = None, block_b: int | None = None,
+                  mode: str = "none"):
+    """(block_q, block_b) for a fused kernel over ``index`` (a
+    ``DeviceIndex``, or a ``QuantizedDeviceIndex`` with its ``mode``)."""
+    n, B = index.n, index.series.shape[0]
     if block_q is None or block_b is None:
         bq, bb = kernel_ops.choose_fused_blocks(
-            Q, index.series.shape[0], index.n, index.levels, index.alphabet,
-            k=k)
+            Q, B, n, index.levels, index.alphabet, k=k, mode=mode)
         block_q, block_b = block_q or bq, block_b or bb
     # Caller-supplied dimensions (either or both) bypass the chooser's
     # feasibility scan — re-check the final shape against the VMEM budget
     # so a mixed override cannot compile an overflowing kernel.
     need = kernel_ops.fused_vmem_bytes(
-        int(block_q), int(block_b), index.n, index.levels, index.alphabet, k)
-    if need > kernel_ops.VMEM_BYTES:
+        int(block_q), int(block_b), n, index.levels, index.alphabet, k,
+        mode)
+    if need > kernel_ops.vmem_limit():
         raise ValueError(
             f"fused blocks block_q={block_q}, block_b={block_b} need "
             f"~{need / 2**20:.1f} MiB VMEM "
-            f"(> {kernel_ops.VMEM_BYTES / 2**20:.0f} MiB); shrink them")
+            f"(> {kernel_ops.vmem_limit() / 2**20:.0f} MiB); shrink them")
     return int(block_q), int(block_b)
 
 
@@ -1471,13 +1492,52 @@ def quantized_screen(
     alive = quantized_cascade_mask(qindex, qr, eps)
     u = _dequant_series_dev(qindex)
     qn = jnp.sum(qr.q * qr.q, axis=-1)
-    cross = jnp.dot(qr.q, u.T, preferred_element_type=jnp.float32)
+    cross = jnp.dot(qr.q, u.T, precision=_F32,
+                    preferred_element_type=jnp.float32)
     d2 = jnp.maximum(qn[:, None] - 2.0 * cross + qindex.norms_sq[None, :],
                      0.0)
     thresh = (eps + qindex.series_err[None, :]) * \
         (1.0 + QUANT_SCREEN_REL) + QUANT_SCREEN_ABS
     keep = alive & (d2 <= thresh * thresh)
     return keep, jnp.where(keep, d2, jnp.inf)
+
+
+def _screen_upper_bounds(qindex: QuantizedDeviceIndex, q: jnp.ndarray,
+                         d2hat: jnp.ndarray) -> jnp.ndarray:
+    """(Q, B) upper bounds on the true distance d(u, q) of every row the
+    screen kept (+inf elsewhere): d(u,q) ≤ d(û,q) + e_u by the triangle
+    inequality, with the matmul-form d̂² raised by an allowance for its
+    f32 error, n·2⁻²⁰·(‖q‖² + ‖û‖²) — sixteen times the worst-case error
+    of an n-term f32 dot, room for the MXU's multi-pass f32."""
+    qn = jnp.sum(q * q, axis=-1, keepdims=True)
+    tol = qindex.n * 2.0 ** -20 * (qn + qindex.norms_sq[None, :])
+    return jnp.sqrt(d2hat + tol) + qindex.series_err[None, :]
+
+
+def _tighten_keep(qindex: QuantizedDeviceIndex, keep, d2hat, eps, radius,
+                  knn_col):
+    """Re-apply the widened series screen to the k-NN rows (``knn_col``)
+    at ``min(eps, radius)``, where ``radius`` upper-bounds each row's true
+    k-th neighbour distance.  Every true neighbour is then within the
+    tighter radius, so the kept set stays a superset of the answer."""
+    tight = jnp.minimum(eps, radius)
+    thresh = (tight + qindex.series_err[None, :]) * \
+        (1.0 + QUANT_SCREEN_REL) + QUANT_SCREEN_ABS
+    return jnp.where(knn_col, keep & (d2hat <= thresh * thresh), keep)
+
+
+@functools.partial(jax.jit, static_argnames=("k",))
+def _tighten_tiered_keep(qindex: QuantizedDeviceIndex, q, keep, d2hat, eps,
+                         knn_col, k: int):
+    """k-NN radius from the screen itself.  The seed radius (k-th of a
+    64-row sample) keeps about k/64 of the database, which at millions of
+    rows is more candidates than the raw-tier verify can gather.  Any k
+    distinct rows bound the k-th neighbour distance by their largest
+    upper bound, so the k-th smallest :func:`_screen_upper_bounds`
+    (slacked) is a sound radius, within about 2·e_u of the true one."""
+    radius = _slacked(_kth_smallest(_screen_upper_bounds(qindex, q, d2hat),
+                                    k))
+    return _tighten_keep(qindex, keep, d2hat, eps, radius, knn_col)
 
 
 @functools.partial(jax.jit, static_argnames=("capacity",))
@@ -1611,14 +1671,8 @@ def _quantized_screen_backend(tindex: TieredIndex, qr: QueryReprDev,
 def _fused_blocks_quant(qdev: QuantizedDeviceIndex, Q: int,
                         block_q: int | None = None,
                         block_b: int | None = None):
-    """Block shapes for the quantized kernels: the full-precision chooser
-    is a conservative upper bound on the quantized VMEM footprint (every
-    quantized input is the same size or smaller), so reuse it."""
-    return _fused_blocks(
-        DeviceIndex(series=qdev.series, norms_sq=qdev.norms_sq,
-                    words=qdev.words, residuals=qdev.residuals,
-                    levels=qdev.levels, alphabet=qdev.alphabet),
-        Q, 0, block_q, block_b)
+    """Block shapes for the quantized kernels (their own VMEM layout)."""
+    return _fused_blocks(qdev, Q, 0, block_q, block_b, mode=qdev.mode)
 
 
 def _raw_rows(raw, idx, key: str = "0") -> jnp.ndarray:
@@ -1777,7 +1831,9 @@ def quantized_knn_query(
     sampled distance upper-bounds the true k-th distance), screens the
     quantized tier at the slacked radius — every true neighbour has
     d ≤ d_k ≤ ε, and the widened screen never kills a row with d ≤ ε —
-    then exact-verifies the surviving candidates from the raw tier and
+    shrinks the radius from the screen's own distance bounds
+    (:func:`_tighten_tiered_keep`), then exact-verifies the surviving
+    candidates from the raw tier and
     takes their top-k (ties to the lowest index, the engine-wide order).
     Capacity escalates on overflow up to B, so ``exact`` is always True
     on return: the answer provably equals brute force.  Knobs ride in
@@ -1792,9 +1848,10 @@ def quantized_knn_query(
     capacity, max_doublings = opts.capacity, opts.max_doublings
     Q, B = qr.q.shape[0], tindex.size
     k_eff = min(int(k), B)
-    eps = _tiered_seed_eps(tindex, qr, k_eff)                # (Q, 1)
-    keep, _ = _quantized_screen_backend(tindex, qr, _slacked(eps),
-                                        opts.backend)
+    eps = _slacked(_tiered_seed_eps(tindex, qr, k_eff))      # (Q, 1)
+    keep, d2hat = _quantized_screen_backend(tindex, qr, eps, opts.backend)
+    keep = _tighten_tiered_keep(tindex.dev, qr.q, keep, d2hat, eps,
+                                jnp.ones((Q, 1), bool), k_eff)
     cap = min(B, max(4 * k_eff, 64) if capacity is None else int(capacity))
     cap = max(cap, k_eff)
     for _ in range(max_doublings + 1):
@@ -1839,7 +1896,10 @@ def quantized_mixed_query(
     eps_req = _eps_qcol(epsilon, Q)
     eps = jnp.where(knn_col, _slacked(_tiered_seed_eps(tindex, qr, k_eff)),
                     eps_req)
-    keep, _ = _quantized_screen_backend(tindex, qr, eps, opts.backend)
+    keep, d2hat = _quantized_screen_backend(tindex, qr, eps, opts.backend)
+    if np.asarray(is_knn).any():          # range-only batches keep ε as is
+        keep = _tighten_tiered_keep(tindex.dev, qr.q, keep, d2hat, eps,
+                                    knn_col, k_eff)
     cap = min(B, max(4 * k_eff, 64) if capacity is None else int(capacity))
     cap = max(cap, k_eff)
     for _ in range(max_doublings + 1):
@@ -2150,7 +2210,8 @@ def quantized_cascade_trace(
         after_c10.append(_count_alive(alive))
     u = _dequant_series_dev(qindex)
     qn = jnp.sum(qr.q * qr.q, axis=-1)
-    cross = jnp.dot(qr.q, u.T, preferred_element_type=jnp.float32)
+    cross = jnp.dot(qr.q, u.T, precision=_F32,
+                    preferred_element_type=jnp.float32)
     d2 = jnp.maximum(qn[:, None] - 2.0 * cross + qindex.norms_sq[None, :],
                      0.0)
     thresh = (eps + qindex.series_err[None, :]) * \

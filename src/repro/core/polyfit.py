@@ -15,8 +15,17 @@ No iterative solver; one pass over the data; batched over (series × segment).
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+
+
+def _dot_abscissa(segs: jnp.ndarray, xc: jnp.ndarray) -> jnp.ndarray:
+    """Σ_l segs[..., l]·xc[l] at full f32 precision: a default-precision
+    contraction runs as one bf16 pass on the TPU, which would perturb the
+    residuals the C9 lower bound compares."""
+    return jnp.einsum("...l,l->...", segs, xc,
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 def _centred_abscissa(seg_len: int):
@@ -37,7 +46,7 @@ def linfit_coeffs(x: jnp.ndarray, n_segments: int):
     if L == 1:
         slope = jnp.zeros_like(mean)
     else:
-        slope = jnp.einsum("...l,l->...", segs, xc) / sxx
+        slope = _dot_abscissa(segs, xc) / sxx
     return mean, slope
 
 
@@ -61,9 +70,9 @@ def linfit_residual_sq(x: jnp.ndarray, n_segments: int) -> jnp.ndarray:
         # L==1: exact fit; L==2: a line through 2 points is exact.
         per_seg = jnp.zeros_like(mean) if L == 1 else jnp.maximum(
             sum_y2 - L * mean * mean
-            - (jnp.einsum("...l,l->...", segs, xc) ** 2) / sxx, 0.0)
+            - (_dot_abscissa(segs, xc) ** 2) / sxx, 0.0)
     else:
-        sxy = jnp.einsum("...l,l->...", segs, xc)
+        sxy = _dot_abscissa(segs, xc)
         per_seg = jnp.maximum(sum_y2 - L * mean * mean - (sxy * sxy) / sxx, 0.0)
     return per_seg.sum(axis=-1)
 

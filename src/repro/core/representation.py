@@ -389,7 +389,7 @@ class TrendSlopeRepr(Representation):
             scaled = jnp.zeros(segs.shape[:-1], dtype=x.dtype)
         else:
             xc, sxx = polyfit._centred_abscissa(L)
-            scaled = jnp.einsum("...l,l->...", segs, xc) / jnp.sqrt(sxx)
+            scaled = polyfit._dot_abscissa(segs, xc) / jnp.sqrt(sxx)
         return discretize(scaled, alphabet)
 
     def host_bound_sq(self, col, qval, *, n, N, alphabet):

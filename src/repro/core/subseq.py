@@ -508,11 +508,11 @@ def _subseq_blocks(sidx: SubseqDeviceIndex, Q: int, k: int = 0,
     need = kernel_ops.subseq_vmem_bytes(
         int(block_q), int(block_w), sidx.window, sidx.stride, sidx.levels,
         sidx.alphabet, k)
-    if need > kernel_ops.VMEM_BYTES:
+    if need > kernel_ops.vmem_limit():
         raise ValueError(
             f"subseq blocks block_q={block_q}, block_w={block_w} need "
             f"~{need / 2**20:.1f} MiB VMEM "
-            f"(> {kernel_ops.VMEM_BYTES / 2**20:.0f} MiB); shrink them")
+            f"(> {kernel_ops.vmem_limit() / 2**20:.0f} MiB); shrink them")
     return int(block_q), int(block_w)
 
 
@@ -557,9 +557,9 @@ def subseq_range_query(
     (the streaming kernel hard-codes the canonical pair)."""
     options = _engine._coerce_options(options, legacy)
     opts, pallas_kw = resolve_options(options, legacy, "subseq_range_query")
-    if _engine.stack_backend(sidx.index,
-                             _engine.resolve_backend(opts.backend)) \
-            == "pallas":
+    if _engine.stack_backend(
+            sidx.index, _engine.resolve_backend(opts.backend,
+                                                streaming=True)) == "pallas":
         return subseq_range_query_pallas(sidx, qr, epsilon, **pallas_kw)
     return _engine.range_query(sidx.index, qr, epsilon)
 
@@ -602,8 +602,9 @@ def _subseq_knn_fetch(sidx, qr, kf, opts,
     """Shared fetch for the k-NN entrypoints: the whole-series exact
     k-NN path at the provably-sufficient fetch count, with extended
     stacks demoting Pallas to XLA."""
-    be = _engine.stack_backend(sidx.index,
-                               _engine.resolve_knn_backend(opts.backend, kf))
+    be = _engine.stack_backend(
+        sidx.index, _engine.resolve_knn_backend(opts.backend, kf,
+                                                streaming=True))
     if be == "pallas":
         return _subseq_knn_pallas(sidx, qr, kf, opts.n_iters,
                                   block_q, block_w, interpret)

@@ -79,42 +79,51 @@ def _split_refs(refs, n_levels: int):
     return q_ref, qn_ref, eps_ref, qlv, series_ref, norms_ref, dlv, outs
 
 
+def _c10_alive(eps2, tq, words, *, alphabet, n, N):
+    """(block_q, block_b) C10 (eq. 10) survivors: the batched per-query-panel
+    compare-select sweep selects ``tq[q, words[b, i], i]`` into a
+    (block_q, block_b, N) accumulator — the engine's ``tab[words, qwords]``
+    gather element for element — before the engine's squared-sum reduction.
+    Symbols compare as int32: the chip's vector unit has no int8 compare."""
+    sel = words.astype(jnp.int32)[None, :, :]          # (1, block_b, N)
+    acc = jnp.zeros((tq.shape[0], words.shape[0], N), jnp.float32)
+    for a in range(alphabet):
+        acc = jnp.where(sel == a, tq[:, a, :][:, None, :], acc)
+    md_sq = (float(n) / N) * jnp.sum(acc * acc, axis=-1)
+    return md_sq <= eps2
+
+
 def _cascade_alive(eps, qlv, dlv, *, levels, alphabet, n):
     """(block_q, block_b) alive mask: every cascade level, VMEM-resident.
 
     Bit-identical to ``core/engine.py::cascade_mask``: the C9 gap is the
-    same subtract/abs, and the select-sweep accumulator reproduces the
-    engine's ``tab[words, qwords]`` gather element-for-element before the
-    identical squared-sum reduction.
+    same subtract/abs and C10 is :func:`_c10_alive`.
     """
     eps2 = eps * eps
     alive = None
     for li, N in enumerate(levels):
         qres = qlv[2 * li][...]                      # (block_q, 1)
-        tq = qlv[2 * li + 1][...]                    # (block_q, alpha, N)
-        res = dlv[2 * li][...]                       # (block_b, 1)
-        words = dlv[2 * li + 1][...]                 # (block_b, N)
+        res = dlv[2 * li][...]                       # (1, block_b)
         # C9 (eq. 9): |d(u,ū) − d(q,q̄)| > ε kills.
-        gap = jnp.abs(res[:, 0][None, :] - qres)     # (block_q, block_b)
-        ok = gap <= eps
+        ok = jnp.abs(res - qres) <= eps
         alive = ok if alive is None else alive & ok
-        # C10 (eq. 10): batched per-query-panel compare-select sweep.
-        sel = words[None, :, :]                      # (1, block_b, N)
-        acc = jnp.zeros((qres.shape[0], words.shape[0], N), jnp.float32)
-        for a in range(alphabet):
-            acc = jnp.where(sel == a, tq[:, a, :][:, None, :], acc)
-        md_sq = (float(n) / N) * jnp.sum(acc * acc, axis=-1)
-        alive &= md_sq <= eps2
+        alive &= _c10_alive(eps2, qlv[2 * li + 1][...],
+                            dlv[2 * li + 1][...], alphabet=alphabet, n=n,
+                            N=N)
     return alive
 
 
 def _verify_arrays(q, qn, series, norms):
-    """(block_q, block_b) squared distances — the engine's matmul form.
-    Takes VMEM-resident arrays so both the whole-series kernels (series
-    read from HBM) and the streaming subsequence kernels (windows built
-    in VMEM) share one verify expression."""
-    cross = jnp.dot(q, series.T, preferred_element_type=jnp.float32)
-    d2 = qn - 2.0 * cross + norms[:, 0][None, :]
+    """(block_q, block_b) squared distances from the (block_b, n) rows and
+    their (1, block_b) squared norms — the engine's matmul form, at full
+    f32 precision (the chip's default f32 matmul is one bf16 pass, about
+    one unit of d² off at n=256).  Takes VMEM-resident arrays so both the
+    whole-series kernels (series read from HBM) and the streaming
+    subsequence kernels (windows built in VMEM) share one verify
+    expression."""
+    cross = jnp.dot(q, series.T, precision=jax.lax.Precision.HIGHEST,
+                    preferred_element_type=jnp.float32)
+    d2 = qn - 2.0 * cross + norms
     return jnp.maximum(d2, 0.0)
 
 
@@ -139,18 +148,47 @@ def _topk_select(d2m, base, k):
     """Unrolled k-sweep min/argmin block-local selection (ties resolve to
     the lowest column, the engine-wide tie-break): (vals (bq, k),
     idx (bq, k)) with +inf / −1 on empty slots.  Shared by the
-    whole-series and streaming-subsequence top-k kernels.  The unroll is
-    why large k belongs on the XLA engine (cost_model
+    whole-series and streaming-subsequence top-k kernels.  Each sweep is
+    two lane reductions and selects (the argmin is the lowest column that
+    holds the minimum), which Mosaic lowers at any k.  The unroll is why
+    large k belongs on the XLA engine (cost_model
     PALLAS_TOPK_UNROLL_MAX)."""
+    bq, width = d2m.shape
     cols = jax.lax.broadcasted_iota(jnp.int32, d2m.shape, 1)
-    vals, idxs = [], []
-    for _ in range(k):                               # k static, unrolled
-        v = jnp.min(d2m, axis=-1)                    # (block_q,)
-        am = jnp.argmin(d2m, axis=-1).astype(jnp.int32)  # ties → lowest col
-        vals.append(v)
-        idxs.append(jnp.where(jnp.isfinite(v), base + am, -1))
-        d2m = jnp.where(cols == am[:, None], jnp.inf, d2m)
-    return jnp.stack(vals, axis=-1), jnp.stack(idxs, axis=-1)
+    slot = jax.lax.broadcasted_iota(jnp.int32, (bq, k), 1)
+    vals = jnp.full((bq, k), jnp.inf, jnp.float32)
+    idxs = jnp.full((bq, k), -1, jnp.int32)
+    for t in range(k):                               # k static, unrolled
+        v = jnp.min(d2m, axis=-1, keepdims=True)     # (bq, 1)
+        am = jnp.min(jnp.where(d2m == v, cols, width), axis=-1,
+                     keepdims=True)                  # ties → lowest col
+        vals = jnp.where(slot == t, v, vals)
+        idxs = jnp.where(slot == t, jnp.where(v < jnp.inf, base + am, -1),
+                         idxs)
+        d2m = jnp.where(cols == am, jnp.inf, d2m)
+    return vals, idxs
+
+
+def _topk_out(Qp: int, nb: int, block_q: int, k: int):
+    """(out_specs, out_shape) of the block-local top-k partials.  Each
+    grid step writes one (block_q, k) tile of an (nb, Qp, k) array: its
+    last two block dims equal the array's, which Mosaic accepts for any k
+    (a (block_q, k) tile of a (Qp, nb·k) array needs k % 128 == 0)."""
+    spec = pl.BlockSpec((None, block_q, k), lambda j, i: (j, i, 0))
+    return ([spec, spec],
+            [jax.ShapeDtypeStruct((nb, Qp, k), jnp.float32),
+             jax.ShapeDtypeStruct((nb, Qp, k), jnp.int32)])
+
+
+def _flatten_partials(vals, idx, Q: int):
+    """(nb, Qp, k) partials -> (Q, nb·k), slot ``j·k + t`` = block j's
+    t-th candidate."""
+    nb, Qp, k = vals.shape
+
+    def flat(a):
+        return jnp.transpose(a, (1, 0, 2)).reshape(Qp, nb * k)[:Q]
+
+    return flat(idx), flat(vals)
 
 
 def _fused_topk_kernel(*refs, levels, alphabet, n, k, block_b):
@@ -179,6 +217,20 @@ def _pad_rows(x, block, fill=0.0):
     return jnp.pad(x, pad, constant_values=fill)
 
 
+def _row(x, block, fill=0.0):
+    """A per-row vector (R,) or (R, 1) as a lane-dense (1, Rp) row, padded
+    to a multiple of ``block`` with ``fill``.  Per-row database columns
+    cross into the kernels in this layout: a (R, 1) operand is laid out in
+    HBM with its one column padded to 128 lanes — 128× its size."""
+    return _pad_rows(x.reshape(-1), block, fill=fill).reshape(1, -1)
+
+
+def _row_spec(block):
+    """BlockSpec of the (1, block) slice of a :func:`_row` column at the
+    outer (database) grid index."""
+    return pl.BlockSpec((1, block), lambda j, i: (0, j))
+
+
 def _query_specs(levels, alphabet, n, block_q):
     """Query-side BlockSpecs (index maps depend only on the INNER grid
     index i) — shared by every kernel family in this module."""
@@ -201,9 +253,9 @@ def _common_specs(levels, alphabet, n, block_q, block_b):
     sweep."""
     in_specs = _query_specs(levels, alphabet, n, block_q)
     in_specs.append(pl.BlockSpec((block_b, n), lambda j, i: (j, 0)))  # series
-    in_specs.append(pl.BlockSpec((block_b, 1), lambda j, i: (j, 0)))  # norms
+    in_specs.append(_row_spec(block_b))                               # norms
     for N in levels:
-        in_specs.append(pl.BlockSpec((block_b, 1), lambda j, i: (j, 0)))
+        in_specs.append(_row_spec(block_b))
         in_specs.append(pl.BlockSpec((block_b, N), lambda j, i: (j, 0)))
     return in_specs
 
@@ -226,16 +278,13 @@ def _prep_query_inputs(q, q_panels, q_residuals, eps_col, levels, block_q):
 def _prep_inputs(series, norms_sq, words, residuals, q, q_panels,
                  q_residuals, eps_col, levels, block_q, block_b):
     """Pad both axes and assemble the flat input list (see _split_refs)."""
-    B = series.shape[0]
     inputs, Qp = _prep_query_inputs(q, q_panels, q_residuals, eps_col,
                                     levels, block_q)
     series_p = _pad_rows(series.astype(jnp.float32), block_b)
-    norms_p = _pad_rows(norms_sq.astype(jnp.float32).reshape(B, 1), block_b)
-    inputs += [series_p, norms_p]
+    inputs += [series_p, _row(norms_sq.astype(jnp.float32), block_b)]
     for li in range(len(levels)):
-        inputs.append(_pad_rows(
-            residuals[li].astype(jnp.float32).reshape(B, 1), block_b,
-            fill=PAD_RESIDUAL))
+        inputs.append(_row(residuals[li].astype(jnp.float32), block_b,
+                           fill=PAD_RESIDUAL))
         inputs.append(_pad_rows(words[li].astype(jnp.int32), block_b))
     return inputs, Qp, series_p.shape[0]
 
@@ -318,28 +367,23 @@ def fused_topk_pallas(
     body — and its compile time — grows linearly in k; for very large k
     the dense XLA ``lax.top_k`` path is the better engine.
     """
-    B, Q = series.shape[0], q.shape[0]
+    Q = q.shape[0]
     inputs, Qp, Bp = _prep_inputs(series, norms_sq, words, residuals,
                                   q, q_panels, q_residuals, eps_col,
                                   levels, block_q, block_b)
     nb = Bp // block_b
     grid = (nb, Qp // block_q)
+    out_specs, out_shape = _topk_out(Qp, nb, block_q, k)
     vals, idx = pl.pallas_call(
         functools.partial(_fused_topk_kernel, levels=levels,
                           alphabet=alphabet, n=n, k=k, block_b=block_b),
         grid=grid,
         in_specs=_common_specs(levels, alphabet, n, block_q, block_b),
-        out_specs=[
-            pl.BlockSpec((block_q, k), lambda j, i: (i, j)),
-            pl.BlockSpec((block_q, k), lambda j, i: (i, j)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((Qp, nb * k), jnp.float32),
-            jax.ShapeDtypeStruct((Qp, nb * k), jnp.int32),
-        ],
+        out_specs=out_specs,
+        out_shape=out_shape,
         interpret=interpret,
     )(*inputs)
-    return idx[:Q], vals[:Q]
+    return _flatten_partials(vals, idx, Q)
 
 
 def merge_topk_partials(idx: jnp.ndarray, d2: jnp.ndarray, k: int):
@@ -399,7 +443,7 @@ def _subseq_z_block(seg_ref, mu_ref, sd_ref, *, window, stride, block_w):
     span = (block_w - 1) * stride + 1
     cols = [seg[0, j:j + span:stride] for j in range(window)]
     win = jnp.stack(cols, axis=1)                    # (block_w, window)
-    return (win - mu_ref[...]) / sd_ref[...]
+    return (win - mu_ref[...].T) / sd_ref[...].T
 
 
 def _subseq_range_kernel(*refs, levels, alphabet, window, stride, block_w):
@@ -438,9 +482,11 @@ def _subseq_layout(streams, window: int, stride: int, block_w: int):
 
     Returns ``(W_s, W_sp, nb, segments)``: canonical windows per stream,
     padded windows per stream (multiple of block_w, so blocks never span
-    streams), total block count, and the (nb, seg_len) f32 segment array
+    streams), total block count, and the f32 segment array
     cut by one gather (positions clipped to the owning stream — the
-    clipped samples feed only sentinel-killed padded windows)."""
+    clipped samples feed only sentinel-killed padded windows), laid out
+    (nb, 1, seg_len) so a (1, seg_len) block spans its array's last two
+    dims, the form Mosaic accepts at any seg_len."""
     S, n_stream = streams.shape
     W_s = (n_stream - window) // stride + 1
     W_sp = -(-W_s // block_w) * block_w
@@ -455,7 +501,7 @@ def _subseq_layout(streams, window: int, stride: int, block_w: int):
     pos = jnp.clip(seg_start[:, None]
                    + jnp.arange(seg_len, dtype=jnp.int32)[None, :],
                    0, lim[:, None])
-    return W_s, W_sp, nb, flat[pos]
+    return W_s, W_sp, nb, flat[pos][:, None, :]
 
 
 def _pad_windows(x, S: int, W_s: int, W_sp: int, fill):
@@ -467,11 +513,15 @@ def _pad_windows(x, S: int, W_s: int, W_sp: int, fill):
         S * W_sp, *x.shape[1:])
 
 
+def _window_row(x, S: int, W_s: int, W_sp: int, fill):
+    """A canonical per-window vector (W,) as a padded (1, S·W_sp) row."""
+    return _pad_windows(x.reshape(-1), S, W_s, W_sp, fill).reshape(1, -1)
+
+
 def _subseq_prep(streams, mu, sd, norms_sq, words, residuals,
                  q, q_panels, q_residuals, eps_col, levels,
                  window, stride, block_q, block_w):
     S = streams.shape[0]
-    W = mu.shape[0]
     q_inputs, Qp = _prep_query_inputs(q, q_panels, q_residuals, eps_col,
                                       levels, block_q)
     W_s, W_sp, nb, segments = _subseq_layout(streams, window, stride,
@@ -479,14 +529,13 @@ def _subseq_prep(streams, mu, sd, norms_sq, words, residuals,
     f32 = jnp.float32
     db_inputs = [
         segments,
-        _pad_windows(mu.astype(f32).reshape(W, 1), S, W_s, W_sp, 0.0),
-        _pad_windows(sd.astype(f32).reshape(W, 1), S, W_s, W_sp, 1.0),
-        _pad_windows(norms_sq.astype(f32).reshape(W, 1), S, W_s, W_sp, 0.0),
+        _window_row(mu.astype(f32), S, W_s, W_sp, 0.0),
+        _window_row(sd.astype(f32), S, W_s, W_sp, 1.0),
+        _window_row(norms_sq.astype(f32), S, W_s, W_sp, 0.0),
     ]
     for li in range(len(levels)):
-        db_inputs.append(_pad_windows(
-            residuals[li].astype(f32).reshape(W, 1), S, W_s, W_sp,
-            PAD_RESIDUAL))
+        db_inputs.append(_window_row(residuals[li].astype(f32), S, W_s,
+                                     W_sp, PAD_RESIDUAL))
         db_inputs.append(_pad_windows(
             words[li].astype(jnp.int32), S, W_s, W_sp, 0))
     return q_inputs + db_inputs, Qp, W_s, W_sp, nb, segments.shape[-1]
@@ -494,11 +543,12 @@ def _subseq_prep(streams, mu, sd, norms_sq, words, residuals,
 
 def _subseq_specs(levels, alphabet, window, seg_len, block_q, block_w):
     in_specs = _query_specs(levels, alphabet, window, block_q)
-    in_specs.append(pl.BlockSpec((1, seg_len), lambda j, i: (j, 0)))  # seg
+    in_specs.append(                                 # stream segment
+        pl.BlockSpec((None, 1, seg_len), lambda j, i: (j, 0, 0)))
     for _ in range(3):                               # mu, sd, norms
-        in_specs.append(pl.BlockSpec((block_w, 1), lambda j, i: (j, 0)))
+        in_specs.append(_row_spec(block_w))
     for N in levels:
-        in_specs.append(pl.BlockSpec((block_w, 1), lambda j, i: (j, 0)))
+        in_specs.append(_row_spec(block_w))
         in_specs.append(pl.BlockSpec((block_w, N), lambda j, i: (j, 0)))
     return in_specs
 
@@ -590,6 +640,7 @@ def fused_subseq_topk_pallas(
         streams, mu, sd, norms_sq, words, residuals, q, q_panels,
         q_residuals, eps_col, levels, window, stride, block_q, block_w)
     grid = (nb, Qp // block_q)
+    out_specs, out_shape = _topk_out(Qp, nb, block_q, k)
     vals, idx = pl.pallas_call(
         functools.partial(_subseq_topk_kernel, levels=levels,
                           alphabet=alphabet, window=window, stride=stride,
@@ -597,21 +648,15 @@ def fused_subseq_topk_pallas(
         grid=grid,
         in_specs=_subseq_specs(levels, alphabet, window, seg_len, block_q,
                                block_w),
-        out_specs=[
-            pl.BlockSpec((block_q, k), lambda j, i: (i, j)),
-            pl.BlockSpec((block_q, k), lambda j, i: (i, j)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((Qp, nb * k), jnp.float32),
-            jax.ShapeDtypeStruct((Qp, nb * k), jnp.int32),
-        ],
+        out_specs=out_specs,
+        out_shape=out_shape,
         interpret=interpret,
     )(*inputs)
+    idx, vals = _flatten_partials(vals, idx, Q)
     # Kernel indices live in the padded (S, W_sp) window space; map them to
     # canonical stream-major ids and kill padded-tail windows explicitly
     # (their sentinel residual already excludes them at any finite ε —
     # this also makes the mapping radius-independent).
-    idx, vals = idx[:Q], vals[:Q]
     s = idx // W_sp
     t = idx % W_sp
     ok = (idx >= 0) & (t < W_s)
@@ -635,18 +680,14 @@ def fused_subseq_topk_pallas(
 # the raw samples anyway, so its in-kernel verify is already exact and it
 # emits final answers directly.
 #
-# Scale-block layout: ``quantized.RESID_BLOCK`` (128) divides every
-# ``block_b`` candidate, so a kernel block always covers whole scale
-# blocks; the (nb, 1) scale columns ride a (block_b // 128, 1) BlockSpec
-# and are expanded to per-row inside VMEM (pure layout ops).
+# Scale-block layout: the per-scale-block columns (one value per
+# ``quantized.RESID_BLOCK`` rows) are expanded to per-row values by the
+# wrappers, outside the kernel, and cross in the same lane-dense (1, rows)
+# layout as every other per-row column (:func:`_row`).  A
+# (block_b // 128, 1) block is refused by Mosaic (its row count is not a
+# multiple of 8), and expanding it in VMEM needs a reshape Mosaic cannot
+# lower.
 # ---------------------------------------------------------------------------
-
-
-def _expand_block_rows(v: jnp.ndarray, block_b: int) -> jnp.ndarray:
-    """(nbs, 1) per-scale-block values -> (block_b, 1) per-row (consecutive
-    runs of RESID_BLOCK rows — same expansion as the XLA oracle)."""
-    nbs = v.shape[0]
-    return jnp.broadcast_to(v, (nbs, block_b // nbs)).reshape(block_b, 1)
 
 
 def _quant_split_refs(refs, n_levels: int, int8: bool):
@@ -655,7 +696,8 @@ def _quant_split_refs(refs, n_levels: int, int8: bool):
     Inputs: q, qnorm, eps, [qres_l, tq_l]*L,
             qseries(, s_scale, s_zero), serr, norms,
             [codes_l(, scale_l, zero_l), err_l, words_l]*L
-    (the parenthesised refs exist only in int8 mode).
+    (the parenthesised refs exist only in int8 mode; every database-side
+    column is per row).
     """
     q_ref, qn_ref, eps_ref = refs[0], refs[1], refs[2]
     qlv = refs[3:3 + 2 * n_levels]
@@ -676,49 +718,41 @@ def _quant_split_refs(refs, n_levels: int, int8: bool):
             s_zero_ref, serr_ref, norms_ref, dlv, outs)
 
 
-def _quant_level_residuals(dlv, li: int, int8: bool, block_b: int):
-    """Dequantized (block_b, 1) residuals + (block_b, 1) error bound +
-    words ref for one level — ``zero + scale · code`` is THE shared
-    dequantizer (bit-identical to engine._dequant_residuals_dev)."""
+def _quant_residuals(dlv, li: int, int8: bool):
+    """Dequantized (1, rows) residuals + (1, rows) error bound + words ref
+    for one level from per-row columns — ``zero + scale · code`` is THE
+    shared dequantizer (bit-identical to engine._dequant_residuals_dev).
+    Shared by the whole-series and streaming-subsequence kernels."""
     per = 5 if int8 else 3
     off = per * li
     if int8:
-        codes = dlv[off][...]                        # (block_b, 1) i8
-        scale = _expand_block_rows(dlv[off + 1][...], block_b)
-        zero = _expand_block_rows(dlv[off + 2][...], block_b)
-        deq = zero + scale * codes.astype(jnp.float32)
-        res = jnp.where(codes == _quant.SENTINEL_CODE,
+        codes = dlv[off][...]                        # (1, rows) i8
+        deq = dlv[off + 2][...] + dlv[off + 1][...] * \
+            codes.astype(jnp.float32)
+        res = jnp.where(codes.astype(jnp.int32) == _quant.SENTINEL_CODE,
                         jnp.float32(PAD_RESIDUAL), deq)
-        err = _expand_block_rows(dlv[off + 3][...], block_b)
+        err = dlv[off + 3][...]
         words_ref = dlv[off + 4]
     else:
-        res = dlv[off][...].astype(jnp.float32)      # (block_b, 1) bf16
-        err = _expand_block_rows(dlv[off + 1][...], block_b)
+        res = dlv[off][...].astype(jnp.float32)      # (1, rows) bf16
+        err = dlv[off + 1][...]
         words_ref = dlv[off + 2]
     return res, err, words_ref
 
 
-def _quant_cascade_alive(eps, qlv, dlv, *, levels, alphabet, n, int8,
-                         block_b):
-    """(block_q, block_b) alive mask under the WIDENED cascade: C9 compares
+def _quant_cascade_alive(eps, qlv, dlv, *, levels, alphabet, n, int8):
+    """(block_q, rows) alive mask under the WIDENED cascade: C9 compares
     the dequantized gap against ε + e_blk; C10 is the exact unwidened
     compare-select sweep on the losslessly-narrowed int8 symbols."""
     eps2 = eps * eps
     alive = None
     for li, N in enumerate(levels):
         qres = qlv[2 * li][...]                      # (block_q, 1)
-        tq = qlv[2 * li + 1][...]                    # (block_q, alpha, N)
-        res, err, words_ref = _quant_level_residuals(dlv, li, int8, block_b)
-        words = words_ref[...]                       # (block_b, N) i8
-        gap = jnp.abs(res[:, 0][None, :] - qres)     # (block_q, block_b)
-        ok = gap <= eps + err[:, 0][None, :]
+        res, err, words_ref = _quant_residuals(dlv, li, int8)
+        ok = jnp.abs(res - qres) <= eps + err         # (block_q, rows)
         alive = ok if alive is None else alive & ok
-        sel = words[None, :, :]
-        acc = jnp.zeros((qres.shape[0], words.shape[0], N), jnp.float32)
-        for a in range(alphabet):
-            acc = jnp.where(sel == a, tq[:, a, :][:, None, :], acc)
-        md_sq = (float(n) / N) * jnp.sum(acc * acc, axis=-1)
-        alive &= md_sq <= eps2
+        alive &= _c10_alive(eps2, qlv[2 * li + 1][...], words_ref[...],
+                            alphabet=alphabet, n=n, N=N)
     return alive
 
 
@@ -727,8 +761,9 @@ def _quant_screen_d2(q_ref, qn_ref, qseries_ref, s_scale_ref, s_zero_ref,
     """Dequantize the series block in VMEM and evaluate the shared
     matmul-form screen distance d(û, q)² against the dequantized norms."""
     codes = qseries_ref[...]
-    if int8:
-        u = s_zero_ref[...] + s_scale_ref[...] * codes.astype(jnp.float32)
+    if int8:                 # per-row affine: (1, rows) rows -> columns
+        u = s_zero_ref[...].T + s_scale_ref[...].T * \
+            codes.astype(jnp.float32)
     else:
         u = codes.astype(jnp.float32)
     return _verify_arrays(q_ref[...], qn_ref[...], u, norms_ref[...])
@@ -737,20 +772,18 @@ def _quant_screen_d2(q_ref, qn_ref, qseries_ref, s_scale_ref, s_zero_ref,
 def _quant_keep(alive, d2, eps, serr_ref):
     """The widened series screen: keep rows with d(û,q) ≤ (ε + e_u) plus
     the f32 slack — identical expression to the XLA oracle."""
-    serr = serr_ref[...]                             # (block_b, 1)
-    thresh = (eps + serr[:, 0][None, :]) * (1.0 + QUANT_SCREEN_REL) \
+    thresh = (eps + serr_ref[...]) * (1.0 + QUANT_SCREEN_REL) \
         + QUANT_SCREEN_ABS
     return alive & (d2 <= thresh * thresh)
 
 
-def _quant_range_kernel(*refs, levels, alphabet, n, int8, block_b):
+def _quant_range_kernel(*refs, levels, alphabet, n, int8):
     (q_ref, qn_ref, eps_ref, qlv, qseries_ref, s_scale_ref, s_zero_ref,
      serr_ref, norms_ref, dlv,
      (keep_ref, d2_ref)) = _quant_split_refs(refs, len(levels), int8)
     eps = eps_ref[...]
     alive = _quant_cascade_alive(eps, qlv, dlv, levels=levels,
-                                 alphabet=alphabet, n=n, int8=int8,
-                                 block_b=block_b)
+                                 alphabet=alphabet, n=n, int8=int8)
     d2 = _quant_screen_d2(q_ref, qn_ref, qseries_ref, s_scale_ref,
                           s_zero_ref, norms_ref, int8)
     keep = _quant_keep(alive, d2, eps, serr_ref)
@@ -764,8 +797,7 @@ def _quant_topk_kernel(*refs, levels, alphabet, n, k, int8, block_b):
      (vals_ref, idx_ref)) = _quant_split_refs(refs, len(levels), int8)
     eps = eps_ref[...]
     alive = _quant_cascade_alive(eps, qlv, dlv, levels=levels,
-                                 alphabet=alphabet, n=n, int8=int8,
-                                 block_b=block_b)
+                                 alphabet=alphabet, n=n, int8=int8)
     d2 = _quant_screen_d2(q_ref, qn_ref, qseries_ref, s_scale_ref,
                           s_zero_ref, norms_ref, int8)
     d2m = jnp.where(_quant_keep(alive, d2, eps, serr_ref), d2, jnp.inf)
@@ -777,33 +809,26 @@ def _quant_topk_kernel(*refs, levels, alphabet, n, k, int8, block_b):
 
 def _quant_db_specs(levels, int8: bool, n: int, block_b: int):
     """Database-side BlockSpecs of the quantized layout (outer index j):
-    per-scale-block columns ride a (block_b // RESID_BLOCK, 1) spec."""
-    nbs = block_b // _quant.RESID_BLOCK
+    the (block_b, n) series codes and per-row (1, block_b) rows."""
+    def col():
+        return _row_spec(block_b)
+
     specs = [pl.BlockSpec((block_b, n), lambda j, i: (j, 0))]    # qseries
     if int8:
-        specs += [pl.BlockSpec((block_b, 1), lambda j, i: (j, 0)),  # s_scale
-                  pl.BlockSpec((block_b, 1), lambda j, i: (j, 0))]  # s_zero
-    specs += [pl.BlockSpec((block_b, 1), lambda j, i: (j, 0)),      # serr
-              pl.BlockSpec((block_b, 1), lambda j, i: (j, 0))]      # norms
+        specs += [col(), col()]                      # s_scale, s_zero
+    specs += [col(), col()]                          # serr, norms
     for N in levels:
-        specs.append(pl.BlockSpec((block_b, 1), lambda j, i: (j, 0)))
-        if int8:
-            specs += [pl.BlockSpec((nbs, 1), lambda j, i: (j, 0)),
-                      pl.BlockSpec((nbs, 1), lambda j, i: (j, 0))]
-        specs.append(pl.BlockSpec((nbs, 1), lambda j, i: (j, 0)))   # err
+        specs += [col() for _ in range(4 if int8 else 2)]  # codes(,sc,z),err
         specs.append(pl.BlockSpec((block_b, N), lambda j, i: (j, 0)))
     return specs
 
 
-def _pad_scale_rows(a, block_b: int, Bp: int, fill):
-    """Pad a (nb, 1) per-scale-block column to the padded row count's
-    block tally (Bp // RESID_BLOCK rows)."""
-    need = Bp // _quant.RESID_BLOCK
-    a = jnp.asarray(a, jnp.float32).reshape(-1, 1)
-    if a.shape[0] == need:
-        return a
-    return jnp.pad(a, [(0, need - a.shape[0]), (0, 0)],
-                   constant_values=fill)
+def _rows_of_blocks(a, Bp: int, fill):
+    """A (nb, 1) per-scale-block column -> (1, Bp) per-row row: runs of
+    RESID_BLOCK consecutive rows share a value (the XLA oracle's
+    expansion); rows past the stored blocks take ``fill``."""
+    a = jnp.asarray(a, jnp.float32).reshape(-1)
+    return _row(jnp.repeat(a, _quant.RESID_BLOCK)[:Bp], Bp, fill=fill)
 
 
 def _quant_prep_inputs(qdev, q, q_panels, q_residuals, eps_col, block_q,
@@ -819,24 +844,19 @@ def _quant_prep_inputs(qdev, q, q_panels, q_residuals, eps_col, block_q,
     Bp = -(-B // block_b) * block_b
     inputs.append(_pad_rows(qdev.series, block_b, fill=0))
     if int8:
-        inputs.append(_pad_rows(qdev.series_scale, block_b, fill=1.0))
-        inputs.append(_pad_rows(qdev.series_zero, block_b, fill=0.0))
-    inputs.append(_pad_rows(
-        qdev.series_err.astype(jnp.float32).reshape(B, 1), block_b))
-    inputs.append(_pad_rows(
-        qdev.norms_sq.astype(jnp.float32).reshape(B, 1), block_b))
+        inputs.append(_row(qdev.series_scale, block_b, fill=1.0))
+        inputs.append(_row(qdev.series_zero, block_b, fill=0.0))
+    inputs.append(_row(qdev.series_err.astype(jnp.float32), block_b))
+    inputs.append(_row(qdev.norms_sq.astype(jnp.float32), block_b))
     for li in range(len(levels)):
-        codes = qdev.residuals[li].reshape(B, 1)
+        codes = qdev.residuals[li]
         if int8:
-            inputs.append(_pad_rows(codes, block_b,
-                                    fill=_quant.SENTINEL_CODE))
-            inputs.append(_pad_scale_rows(qdev.resid_scale[li], block_b,
-                                          Bp, 1.0))
-            inputs.append(_pad_scale_rows(qdev.resid_zero[li], block_b,
-                                          Bp, 0.0))
+            inputs.append(_row(codes, block_b, fill=_quant.SENTINEL_CODE))
+            inputs.append(_rows_of_blocks(qdev.resid_scale[li], Bp, 1.0))
+            inputs.append(_rows_of_blocks(qdev.resid_zero[li], Bp, 0.0))
         else:
-            inputs.append(_pad_rows(codes, block_b, fill=PAD_RESIDUAL))
-        inputs.append(_pad_scale_rows(qdev.resid_err[li], block_b, Bp, 0.0))
+            inputs.append(_row(codes, block_b, fill=PAD_RESIDUAL))
+        inputs.append(_rows_of_blocks(qdev.resid_err[li], Bp, 0.0))
         inputs.append(_pad_rows(qdev.words[li], block_b, fill=0))
     return inputs, Qp, Bp
 
@@ -852,8 +872,7 @@ def _quant_range_call(inputs, Qp, Bp, mode, levels, alphabet, n, block_q,
         _quant_db_specs(levels, int8, n, block_b)
     return pl.pallas_call(
         functools.partial(_quant_range_kernel, levels=levels,
-                          alphabet=alphabet, n=n, int8=int8,
-                          block_b=block_b),
+                          alphabet=alphabet, n=n, int8=int8),
         grid=grid,
         in_specs=in_specs,
         out_specs=[
@@ -907,20 +926,15 @@ def _quant_topk_call(inputs, Qp, Bp, mode, levels, alphabet, n, k, block_q,
     grid = (nb, Qp // block_q)
     in_specs = _query_specs(levels, alphabet, n, block_q) + \
         _quant_db_specs(levels, int8, n, block_b)
+    out_specs, out_shape = _topk_out(Qp, nb, block_q, k)
     return pl.pallas_call(
         functools.partial(_quant_topk_kernel, levels=levels,
                           alphabet=alphabet, n=n, k=k, int8=int8,
                           block_b=block_b),
         grid=grid,
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((block_q, k), lambda j, i: (i, j)),
-            pl.BlockSpec((block_q, k), lambda j, i: (i, j)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((Qp, nb * k), jnp.float32),
-            jax.ShapeDtypeStruct((Qp, nb * k), jnp.int32),
-        ],
+        out_specs=out_specs,
+        out_shape=out_shape,
         interpret=interpret,
     )(*inputs)
 
@@ -944,7 +958,7 @@ def fused_quant_topk_pallas(
     tiered k-NN engine prefers the range screen + compaction epilogue —
     this form exists for parity testing and candidate generation.
     """
-    B, Q = qdev.series.shape[0], q.shape[0]
+    Q = q.shape[0]
     eps = jnp.asarray(eps_col, jnp.float32).reshape(Q, 1)
     inputs, Qp, Bp = _quant_prep_inputs(qdev, q, q_panels, q_residuals,
                                         eps, block_q, block_b)
@@ -952,7 +966,7 @@ def fused_quant_topk_pallas(
         inputs, Qp=Qp, Bp=Bp, mode=qdev.mode, levels=qdev.levels,
         alphabet=qdev.alphabet, n=qdev.n, k=int(k), block_q=block_q,
         block_b=block_b, interpret=interpret)
-    return idx[:Q], vals[:Q]
+    return _flatten_partials(vals, idx, Q)
 
 
 # --- streaming subsequence form --------------------------------------------
@@ -976,47 +990,13 @@ def _quant_subseq_split_refs(refs, n_levels: int, int8: bool):
             norms_ref, dlv, outs)
 
 
-def _quant_window_residuals(dlv, li: int, int8: bool):
-    """Dequantized (block_w, 1) window residuals + error + words ref —
-    per-window affine params, same ``zero + scale · code`` expression."""
-    per = 5 if int8 else 3
-    off = per * li
-    if int8:
-        codes = dlv[off][...]
-        deq = dlv[off + 2][...] + dlv[off + 1][...] * \
-            codes.astype(jnp.float32)
-        res = jnp.where(codes == _quant.SENTINEL_CODE,
-                        jnp.float32(PAD_RESIDUAL), deq)
-        err = dlv[off + 3][...]
-        words_ref = dlv[off + 4]
-    else:
-        res = dlv[off][...].astype(jnp.float32)
-        err = dlv[off + 1][...]
-        words_ref = dlv[off + 2]
-    return res, err, words_ref
-
-
 def _quant_subseq_range_kernel(*refs, levels, alphabet, window, stride,
                                int8, block_w):
     (q_ref, qn_ref, eps_ref, qlv, seg_ref, mu_ref, sd_ref, norms_ref, dlv,
      (ans_ref, d2_ref)) = _quant_subseq_split_refs(refs, len(levels), int8)
     eps = eps_ref[...]
-    eps2 = eps * eps
-    alive = None
-    for li, N in enumerate(levels):
-        qres = qlv[2 * li][...]
-        tq = qlv[2 * li + 1][...]
-        res, err, words_ref = _quant_window_residuals(dlv, li, int8)
-        words = words_ref[...]
-        gap = jnp.abs(res[:, 0][None, :] - qres)
-        ok = gap <= eps + err[:, 0][None, :]
-        alive = ok if alive is None else alive & ok
-        sel = words[None, :, :]
-        acc = jnp.zeros((qres.shape[0], words.shape[0], N), jnp.float32)
-        for a in range(alphabet):
-            acc = jnp.where(sel == a, tq[:, a, :][:, None, :], acc)
-        md_sq = (float(window) / N) * jnp.sum(acc * acc, axis=-1)
-        alive &= md_sq <= eps2
+    alive = _quant_cascade_alive(eps, qlv, dlv, levels=levels,
+                                 alphabet=alphabet, n=window, int8=int8)
     # The raw samples are streamed anyway, so the in-kernel verify is
     # EXACT — quantization touched only the screen metadata, and the
     # widened cascade is a superset screen: final answers are identical
@@ -1024,7 +1004,7 @@ def _quant_subseq_range_kernel(*refs, levels, alphabet, window, stride,
     z = _subseq_z_block(seg_ref, mu_ref, sd_ref, window=window,
                         stride=stride, block_w=block_w)
     d2 = _verify_arrays(q_ref[...], qn_ref[...], z, norms_ref[...])
-    ans = alive & (d2 <= eps2)
+    ans = alive & (d2 <= eps * eps)
     ans_ref[...] = ans.astype(jnp.int32)
     d2_ref[...] = jnp.where(ans, d2, jnp.inf)
 
@@ -1072,36 +1052,34 @@ def fused_quant_subseq_range_pallas(
     f32 = jnp.float32
     db_inputs = [
         segments,
-        _pad_windows(mu.astype(f32).reshape(W, 1), S, W_s, W_sp, 0.0),
-        _pad_windows(sd.astype(f32).reshape(W, 1), S, W_s, W_sp, 1.0),
-        _pad_windows(norms_sq.astype(f32).reshape(W, 1), S, W_s, W_sp, 0.0),
+        _window_row(mu.astype(f32), S, W_s, W_sp, 0.0),
+        _window_row(sd.astype(f32), S, W_s, W_sp, 1.0),
+        _window_row(norms_sq.astype(f32), S, W_s, W_sp, 0.0),
     ]
     for li in range(len(levels)):
-        codes = qresiduals[li].reshape(W, 1)
+        codes = qresiduals[li]
         if int8:
-            db_inputs.append(_pad_windows(codes, S, W_s, W_sp,
-                                          _quant.SENTINEL_CODE))
-            db_inputs.append(_pad_windows(
-                qresid_scale[li].astype(f32).reshape(W, 1), S, W_s, W_sp,
-                1.0))
-            db_inputs.append(_pad_windows(
-                qresid_zero[li].astype(f32).reshape(W, 1), S, W_s, W_sp,
-                0.0))
+            db_inputs.append(_window_row(codes, S, W_s, W_sp,
+                                         _quant.SENTINEL_CODE))
+            db_inputs.append(_window_row(qresid_scale[li].astype(f32), S,
+                                         W_s, W_sp, 1.0))
+            db_inputs.append(_window_row(qresid_zero[li].astype(f32), S,
+                                         W_s, W_sp, 0.0))
         else:
-            db_inputs.append(_pad_windows(codes, S, W_s, W_sp,
-                                          PAD_RESIDUAL))
-        db_inputs.append(_pad_windows(
-            qresid_err[li].astype(f32).reshape(W, 1), S, W_s, W_sp, 0.0))
+            db_inputs.append(_window_row(codes, S, W_s, W_sp, PAD_RESIDUAL))
+        db_inputs.append(_window_row(qresid_err[li].astype(f32), S, W_s,
+                                     W_sp, 0.0))
         db_inputs.append(_pad_windows(qwords[li], S, W_s, W_sp, 0))
     seg_len = segments.shape[-1]
     in_specs = _query_specs(levels, alphabet, window, block_q)
-    in_specs.append(pl.BlockSpec((1, seg_len), lambda j, i: (j, 0)))
+    in_specs.append(
+        pl.BlockSpec((None, 1, seg_len), lambda j, i: (j, 0, 0)))
     for _ in range(3):
-        in_specs.append(pl.BlockSpec((block_w, 1), lambda j, i: (j, 0)))
+        in_specs.append(_row_spec(block_w))
     for N in levels:
         per = 4 if int8 else 2                       # codes(,scale,zero),err
         for _ in range(per):
-            in_specs.append(pl.BlockSpec((block_w, 1), lambda j, i: (j, 0)))
+            in_specs.append(_row_spec(block_w))
         in_specs.append(pl.BlockSpec((block_w, N), lambda j, i: (j, 0)))
     grid = (nb, Qp // block_q)
     ans, d2 = pl.pallas_call(

@@ -4,11 +4,11 @@ Responsibilities kept out of the kernels themselves:
   * batch padding to the block size (and unpadding of results),
   * the per-query (α, N) MINDIST table panel (cached per alphabet),
   * VMEM budget checks and block-shape selection for the fused megakernel
-    (the latency ranking lives in ``core/cost_model.py`` — the hardware
-    numbers are a model concern, not a kernel concern),
-  * backend dispatch: ``interpret=None`` → interpret mode off TPU (this
-    container is CPU-only; kernels execute via the Pallas interpreter and
-    are validated against ``ref.py``), compiled Pallas on real TPU.
+    (the latency ranking lives in ``core/cost_model.py``; the chip's
+    scoped-VMEM limit and rates in ``runtime/roofline.CHIP_PEAKS``),
+  * backend dispatch: ``interpret=None`` → interpret mode off TPU (CPU
+    test runs execute the kernels through the Pallas interpreter,
+    validated against ``ref.py``), compiled Pallas on a TPU.
 
 Every wrapper has a ``ref.py`` oracle with identical semantics; the XLA
 engine (core/engine.py) uses the oracle expressions directly, so the Pallas
@@ -23,6 +23,7 @@ Euclidean verify in a single database pass.  The single-level
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -34,7 +35,7 @@ from .mindist import mindist_sq_pallas
 from .paa import paa_pallas
 from .sqdist import sqdist_pallas
 
-VMEM_BYTES = 16 * 2 ** 20          # v5e VMEM per core (half, conservatively)
+_LANES = 128
 
 # Candidate fused-megakernel block shapes, largest-first.  block_b is the
 # HBM streaming granularity; block_q amortises each resident database
@@ -42,6 +43,14 @@ VMEM_BYTES = 16 * 2 ** 20          # v5e VMEM per core (half, conservatively)
 # select-sweep accumulator costs).
 FUSED_BLOCK_B = (1024, 512, 256, 128)
 FUSED_BLOCK_Q = (32, 16, 8)
+
+
+def vmem_limit() -> int:
+    """Scoped-VMEM bytes one kernel may use on this process's chip (the
+    target chip where none is attached): ``runtime/roofline.CHIP_PEAKS``."""
+    from ..runtime.roofline import local_peaks
+
+    return local_peaks().vmem_limit
 
 
 def _use_interpret(interpret) -> bool:
@@ -62,10 +71,10 @@ def _pad_rows(x: jnp.ndarray, block_b: int):
 def _check_vmem(block_b: int, n: int, extra: int = 0):
     # database block f32 + constants + output, doubled for pipelining
     need = 2 * (block_b * n * 4 + extra)
-    if need > VMEM_BYTES:
+    if need > vmem_limit():
         raise ValueError(
             f"block_b={block_b}, n={n} needs ~{need/2**20:.1f} MiB VMEM "
-            f"(> {VMEM_BYTES/2**20:.0f} MiB); shrink block_b")
+            f"(> {vmem_limit()/2**20:.0f} MiB); shrink block_b")
 
 
 # ---------------------------------------------------------------------------
@@ -112,37 +121,90 @@ def query_panels(qwords, alphabet: int) -> jnp.ndarray:
 # the kernel layer).
 # ---------------------------------------------------------------------------
 
-def fused_vmem_bytes(block_q: int, block_b: int, n: int, levels,
-                     alphabet: int, k: int = 0) -> int:
-    """Conservative VMEM footprint of one fused-megakernel grid step.
+def _vmem_block(shape, itemsize: int) -> int:
+    """VMEM bytes of one buffer holding a block of ``shape``, laid out as
+    Mosaic lays it out: the last dim padded to 128 lanes and the
+    second-to-last to the dtype's sublane tile (8 rows of 4-byte values,
+    16 of 2-byte, 32 of 1-byte).  A (rows, 1) column therefore costs
+    rows·128·itemsize bytes, not rows·itemsize."""
+    *lead, rows, cols = shape
+    sub = 32 // itemsize
+    return (math.prod(lead) * (-(-rows // sub) * sub)
+            * (-(-cols // _LANES) * _LANES) * itemsize)
 
-    Inputs and outputs are doubled for pipelining; the transient
-    (block_q, block_b, N) select-sweep accumulator is charged once.
+
+# In-kernel temporaries per (query, row) pair, in units of one f32
+# select-sweep accumulator lane row (128 lanes × 4 bytes).  Fitted as an
+# upper bound to the smallest scoped-VMEM limit at which the TPU compiler
+# accepts each kernel, less its padded buffers (v5e, n=256, levels
+# (8, 16), alphabet 10, block_q 8-32 × block_b 128-1024, range, top-k,
+# int8 and bf16 alike): 0.74-1.58 measured.
+_TEMP_ACC = 2
+
+
+def fused_vmem_bytes(block_q: int, block_b: int, n: int, levels,
+                     alphabet: int, k: int = 0, mode: str = "none") -> int:
+    """VMEM footprint of one fused-megakernel grid step, as Mosaic counts
+    it against its scoped-VMEM limit.
+
+    Every input and output block is charged at its padded VMEM layout
+    (:func:`_vmem_block`) and twice (the pipeline double-buffers it); the
+    in-kernel temporaries — chiefly the (block_q, block_b, N) select-sweep
+    accumulator, whose N lanes pad to 128 — are charged per (query, row)
+    pair (``_TEMP_ACC``).  ``mode`` is the database layout: ``"none"``
+    (float32 columns, ``fused_range_pallas`` / ``fused_topk_pallas``) or
+    the quantized ``"int8"`` / ``"bf16"`` layouts of
+    ``fused_quant_range_pallas``.
     """
     levels = tuple(int(N) for N in levels)
-    n_lv = len(levels)
-    db = block_b * (n + 1 + sum(levels) + n_lv) * 4
-    qside = block_q * (n + 2 + n_lv + alphabet * sum(levels)) * 4
-    out = block_q * (2 * k if k else 2 * block_b) * 4
-    acc = block_q * block_b * (max(levels) + 3) * 4   # sweep acc + d2/masks
-    return 2 * (db + qside + out) + acc
+    bq, bb = block_q, block_b
+    row = _vmem_block((1, bb), 4)          # a per-row column, lane-dense
+    blocks = [_vmem_block((bq, n), 4)] + [_vmem_block((bq, 1), 4)] * 2
+    for N in levels:
+        blocks += [_vmem_block((bq, 1), 4),
+                   _vmem_block((bq, alphabet, N), 4)]
+    if mode == "none":
+        blocks += [_vmem_block((bb, n), 4), row]
+        for N in levels:
+            blocks += [row, _vmem_block((bb, N), 4)]
+        temps = 0
+    else:
+        code = 1 if mode == "int8" else 2
+        per_row_f32 = 2 + 2 * (mode == "int8")      # serr, norms(, sc, z)
+        blocks += [_vmem_block((bb, n), code)] + [row] * per_row_f32
+        for N in levels:
+            blocks += [_vmem_block((1, bb), code), _vmem_block((bb, N), 1)]
+            blocks += [row] * (3 if mode == "int8" else 1)
+        # the dequantized series, and the int8 affine's two transposed
+        # (block_b, 1) columns
+        temps = _vmem_block((bb, n), 4) + 2 * _vmem_block((bb, 1), 4)
+    if k:
+        blocks += [_vmem_block((bq, k), 4)] * 2
+    else:
+        blocks += [_vmem_block((bq, bb), 4)] * 2
+    temps += _TEMP_ACC * bq * bb * _LANES * 4
+    return 2 * sum(blocks) + temps
 
 
 def choose_fused_blocks(Q: int, B: int, n: int, levels, alphabet: int,
-                        k: int = 0, vmem: int = VMEM_BYTES):
+                        k: int = 0, vmem: int | None = None,
+                        mode: str = "none"):
     """Pick (block_q, block_b) for the fused megakernel.
 
-    Feasibility is the VMEM budget above; among feasible shapes the
-    cheapest one wins under the latency-model hook
+    Feasibility is the chip's scoped-VMEM limit (``vmem`` defaults to
+    :func:`vmem_limit`) against :func:`fused_vmem_bytes`; among feasible
+    shapes the cheapest one wins under the latency-model hook
     ``core/cost_model.fused_pass_estimate`` (HBM streaming vs compute).
     Raises if nothing fits — the caller should shrink n or levels.
     """
     from ..core import cost_model as _cm
 
+    vmem = vmem_limit() if vmem is None else vmem
     best = None
     for bq in FUSED_BLOCK_Q:
         for bb in FUSED_BLOCK_B:
-            if fused_vmem_bytes(bq, bb, n, levels, alphabet, k) > vmem:
+            if fused_vmem_bytes(bq, bb, n, levels, alphabet, k,
+                                mode) > vmem:
                 continue
             est = _cm.fused_pass_estimate(
                 Q, B, n, levels, alphabet, block_q=bq, block_b=bb, k=k)
@@ -174,13 +236,14 @@ def subseq_vmem_bytes(block_q: int, block_w: int, window: int, stride: int,
 
 def choose_subseq_blocks(Q: int, n_windows: int, window: int, stride: int,
                          levels, alphabet: int, k: int = 0,
-                         vmem: int = VMEM_BYTES):
+                         vmem: int | None = None):
     """Pick (block_q, block_w) for the streaming subsequence kernels —
     VMEM feasibility here, latency ranking by
     ``core/cost_model.subseq_pass_estimate`` (same split as
     :func:`choose_fused_blocks`)."""
     from ..core import cost_model as _cm
 
+    vmem = vmem_limit() if vmem is None else vmem
     best = None
     for bq in FUSED_BLOCK_Q:
         for bw in FUSED_BLOCK_B:

@@ -27,6 +27,7 @@ import numpy as np
 
 from .. import configs
 from ..models.transformer import decode_step, init_params, prefill
+from ..runtime.compile_cache import use_compile_cache
 from ..runtime.sharding import single_device
 from .mesh import make_test_parallelism
 
@@ -572,6 +573,7 @@ def main(argv=None):
                     help="with --trace: jax.profiler capture directory "
                          "wrapped around each dispatch (XLA-level detail)")
     args = ap.parse_args(argv)
+    use_compile_cache()
     if args.serve:
         serve_subseq_service(args) if args.subseq else serve_service(args)
     elif args.search:

@@ -29,6 +29,7 @@ replacement between device calls.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import pathlib
 import threading
 import time
@@ -199,6 +200,19 @@ class _SingleBackend:
         return fused_pass_estimate(Q, self.size, self.n, self.index.levels,
                                    self.index.alphabet, k=int(k))
 
+    def fused_call(self, k: int):
+        """The fused-megakernel device call :meth:`dispatch` makes for the
+        k bucket ``k``, as ``f(index, qr, eps, is_knn)``, or None where
+        the bucket runs the XLA engine.  Large k buckets demote the fused
+        path to XLA (the unrolled in-kernel selection grows linearly in k,
+        DESIGN.md §7); the decision is a pure function of (backend, k
+        bucket), so every batch — and every direct replay — of a bucket
+        takes the same float path."""
+        if resolve_knn_backend(self.backend, k) != "pallas":
+            return None
+        return functools.partial(mixed_query_pallas, k=k,
+                                 n_iters=self.cfg.n_iters)
+
     def dispatch(self, q: np.ndarray, eps: np.ndarray, is_knn: np.ndarray,
                  k: int, want_trace: bool = False):
         B = self.size
@@ -210,20 +224,14 @@ class _SingleBackend:
         eps_j = jnp.asarray(eps, jnp.float32)
         knn_j = jnp.asarray(is_knn)
         self._note_demotion(k)
-        # Large k buckets demote the fused path to XLA (the unrolled
-        # in-kernel selection grows linearly in k, DESIGN.md §7); the
-        # decision is a pure function of (backend, k bucket), so every
-        # batch — and every direct replay — of a bucket takes the same
-        # float path.
         trace = None
-        if resolve_knn_backend(self.backend, k) == "pallas":
+        fused = self.fused_call(k)
+        if fused is not None:
             # One fused megakernel pass per micro-batch: dense layout,
             # no candidate buffer, no capacity escalation (DESIGN.md §7).
             # The jit cache stays keyed on the (Q, k) bucket exactly like
             # the XLA path.
-            idx, answer, d2, overflow = mixed_query_pallas(
-                self.index, qr, eps_j, knn_j, k,
-                n_iters=self.cfg.n_iters)
+            idx, answer, d2, overflow = fused(self.index, qr, eps_j, knn_j)
             if want_trace:
                 trace = mixed_trace(self.index, qr, eps_j, knn_j, k,
                                     answer, d2)
@@ -281,6 +289,8 @@ class _QuantizedBackend:
     def __init__(self, tindex, cfg: ServeConfig):
         self.tindex = tindex
         self.cfg = cfg
+        # The screen's engine (the exact verify is XLA either way).
+        self.backend = stack_backend(tindex.dev, resolve_backend(cfg.backend))
         self._cap: Optional[int] = None
         self.stats: Optional[StatsTracker] = None   # set by SearchService
 
@@ -332,7 +342,7 @@ class _QuantizedBackend:
         cap = self._cap or self.cfg.capacity0 or max(4 * k, 64)
         idx, answer, d2, overflow = quantized_mixed_query(
             self.tindex, qr, eps_j, knn_j, k,
-            options=SearchOptions(backend=self.cfg.backend, capacity=cap,
+            options=SearchOptions(backend=self.backend, capacity=cap,
                                   verify_prefetch=self.cfg.verify_prefetch))
         self._cap = max(cap, self._cap or 0)
         if self.stats is not None:
@@ -815,7 +825,11 @@ class SearchService:
         k_buckets = [
             _pow2_at_least(int(k), self.backend.size)
             for k in (ks if ks is not None else self.cfg.warmup_ks)]
-        probe = np.zeros((1, self.backend.n), dtype=np.float32)
+        # Not a constant: a constant probe z-normalises to zeros, which sit
+        # at the same distance from every z-normalised row, and the tiered
+        # backend's k-NN warmup would then verify the whole database.
+        probe = np.sin(np.linspace(0.0, 6.0 * np.pi, self.backend.n,
+                                   dtype=np.float32))[None, :]
         for qb in q_buckets:
             q = np.repeat(probe, qb, axis=0)
             eps = np.full(qb, 1.0, np.float32)
@@ -1042,14 +1056,11 @@ class SearchService:
             # no extra sync was added to measure it.
             self.tracer.record("dispatch", t0, t1, batch=len(live),
                                bucket=qb, k=k_bucket)
-            try:
-                estimate = self.backend.cost_estimate(qb, k_bucket)
-            except Exception:   # cost model gaps must never fail serving
-                estimate = None
             self.calibration.record(
                 batch=len(live), k=k_bucket,
                 backend=type(self.backend).__name__,
-                measured_s=t1 - t0, estimate=estimate)
+                measured_s=t1 - t0,
+                estimate=self.backend.cost_estimate(qb, k_bucket))
             if trace is not None:
                 with self.tracer.span("verify", batch=len(live)):
                     live_trace = select_queries(trace,

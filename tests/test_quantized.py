@@ -296,6 +296,45 @@ def test_tiered_mixed_set_identical(case, mode):
             assert set(gi[qi][ga[qi]].tolist()) == want_rows
 
 
+def _random_walks(B: int, n: int, seed: int) -> np.ndarray:
+    rng = np.random.default_rng(seed)
+    w = rng.standard_normal((B, n)).cumsum(axis=1)
+    w -= w.mean(axis=1, keepdims=True)
+    return (w / w.std(axis=1, keepdims=True)).astype(np.float32)
+
+
+@pytest.mark.parametrize("mode", MODES)
+def test_tiered_knn_survivors_bounded(mode):
+    """The tiered k-NN radius shrinks to the screen's own k-th upper bound,
+    so a k-NN row verifies a few times k raw rows, not the ~k/64 of the
+    database its 64-row sample seed radius admits (~320 rows here): the
+    raw-tier gather stays small at millions of rows."""
+    B, n, Q, k = 4096, 64, 8, 5
+    db = _random_walks(B, n, seed=0)
+    rng = np.random.default_rng(1)
+    sigma = np.resize([0.1, 0.3, 1.0], Q)[:, None]
+    qs = (db[rng.integers(0, B, Q)]
+          + sigma * rng.standard_normal((Q, n))).astype(np.float32)
+    levels, alpha = (4, 8), 8
+    host = build_index(db, FastSAXConfig(n_segments=levels, alphabet=alpha))
+    tindex = engine.TieredIndex.from_host(host, mode)
+    qr = engine.represent_queries(jnp.asarray(qs), levels, alpha,
+                                  stack=tindex.dev.stack)
+    is_knn = np.arange(Q) % 2 == 0
+    idx, answer, d2, overflow = engine.quantized_mixed_query(
+        tindex, qr, jnp.full((Q,), 1.0, jnp.float32), is_knn, k)
+    assert not bool(np.asarray(overflow).any())
+    survivors = np.asarray(answer).sum(axis=1)[is_knn]
+    assert (survivors >= k).all() and (survivors <= 4 * k).all(), survivors
+    # ... and the tightened set still holds the exact top-k.
+    d2o = ((db[None].astype(np.float64)
+            - np.asarray(qr.q, np.float64)[:, None]) ** 2).sum(-1)
+    got, _ = engine.mixed_topk(idx, d2, k)
+    for qi in np.flatnonzero(is_knn):
+        want = np.lexsort((np.arange(B), d2o[qi]))[:k]
+        np.testing.assert_array_equal(np.asarray(got)[qi], want)
+
+
 @settings(max_examples=6, deadline=None)
 @given(st.integers(0, 2 ** 31 - 1), st.sampled_from(MODES),
        st.sampled_from([0.8, 1.5, 3.0, 50.0]))

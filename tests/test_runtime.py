@@ -54,6 +54,37 @@ def test_roofline_terms_and_dominance():
     assert abs(t.useful_ratio - 0.5) < 1e-9
 
 
+def test_chip_peaks_table_and_unknown_kind():
+    v5e = rl.chip_peaks("TPU v5 lite")
+    assert (v5e.flops, v5e.hbm_bw, v5e.ici_bw) == (197e12, 819e9, 50e9)
+    # Off the chip the target's peaks price the model; an unknown TPU
+    # kind is an error, never a default.
+    assert rl.local_peaks() == rl.CHIP_PEAKS[rl.TARGET_KIND]
+    with pytest.raises(ValueError, match="TPU v9 mega"):
+        rl.chip_peaks("TPU v9 mega")
+
+
+def test_compile_cache_dir(monkeypatch):
+    import pathlib
+
+    from repro.runtime import compile_cache as cc
+
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        # The environment variable stands as JAX reads it: nothing is set.
+        monkeypatch.setenv(cc.ENV_VAR, "/elsewhere/cache")
+        assert cc.use_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == was
+        # Without it, the fixed <checkout>/.jax_cache.
+        monkeypatch.delenv(cc.ENV_VAR)
+        got = pathlib.Path(cc.use_compile_cache())
+        assert got.name == ".jax_cache"
+        assert (got.parent / "pyproject.toml").exists()
+        assert jax.config.jax_compilation_cache_dir == str(got)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+
+
 def test_jaxpr_cost_matmul_exact():
     M, K, N = 128, 64, 32
     c = jaxpr_cost(lambda a, b: a @ b,
