@@ -33,6 +33,7 @@ from ..index import quantized as _quant
 from ..index import store as _store
 from ..kernels import fused_query as _fused
 from ..kernels import ops as kernel_ops
+from ..obs.spans import span
 from ..obs.trace import QueryTrace, screen_row_bytes, tier_bytes
 from . import cost_model as _cost_model
 from . import representation as repr_registry
@@ -1675,17 +1676,6 @@ def _fused_blocks_quant(qdev: QuantizedDeviceIndex, Q: int,
     return _fused_blocks(qdev, Q, 0, block_q, block_b, mode=qdev.mode)
 
 
-def _raw_rows(raw, idx, key: str = "0") -> jnp.ndarray:
-    """Gather candidate rows from the host mmap tier and upload as f32 —
-    the only touch of full-precision data on the query path.  The read
-    goes through ``index.store.gather_rows``: ids clamp into the raw
-    tier's row range (the raw tier may hold fewer rows than the padded
-    screen tier — padded rows are sentinel-killed and their slots are
-    masked), and the ``verify_fetch`` chaos site fires on it."""
-    idx_np = np.asarray(jax.device_get(idx))
-    return jnp.asarray(_store.gather_rows(raw, idx_np, key=key))
-
-
 #: Double-buffer depth of the prefetched verify path: chunk i+1's mmap
 #: read runs on the prefetch thread while chunk i's upload + verify is in
 #: flight on device.
@@ -1729,8 +1719,9 @@ def _verify_prefetched(raw, idx, q, valid, key: str = "") -> jnp.ndarray:
         rows = fut.result()
         if j + 1 < len(spans):
             fut = pool.submit(fetch, j + 1, *spans[j + 1])
-        parts.append(_verify_gathered(jnp.asarray(rows), q,
-                                      valid[:, lo:hi]))
+        with span("repro.engine.verify"):
+            parts.append(_verify_gathered(jnp.asarray(rows), q,
+                                          valid[:, lo:hi]))
     return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=-1)
 
 
@@ -1738,10 +1729,45 @@ def _verify_tier(raw, idx, q, valid, opts: SearchOptions,
                  key: str = "") -> jnp.ndarray:
     """The raw-tier exact verify behind every tiered engine: synchronous
     single gather, or the double-buffered prefetch path when
-    ``opts.verify_prefetch`` — same d2, bit for bit."""
+    ``opts.verify_prefetch`` — same d2, bit for bit.
+
+    The synchronous path gathers the candidate rows from the host mmap
+    tier and uploads them as f32 — the only touch of full-precision data
+    on the query path.  The read goes through ``index.store.gather_rows``:
+    ids clamp into the raw tier's row range (the raw tier may hold fewer
+    rows than the padded screen tier — padded rows are sentinel-killed
+    and their slots are masked), and the ``verify_fetch`` chaos site
+    fires on it."""
     if opts.verify_prefetch:
         return _verify_prefetched(raw, idx, q, valid, key=key)
-    return _verify_gathered(_raw_rows(raw, idx, key=key or "0"), q, valid)
+    rows = _store.gather_rows(raw, np.asarray(jax.device_get(idx)),
+                              key=key or "0")
+    with span("repro.engine.verify"):
+        return _verify_gathered(jnp.asarray(rows), q, valid)
+
+
+def _compact_escalating(screen, cap: int, B: int, max_doublings: int):
+    """Screen, then compact its keep mask, escalating the capacity 4× on
+    overflow (capped at B, where compaction cannot overflow) at most
+    ``max_doublings`` times: ``(idx, valid, overflow)`` of the last step.
+
+    ``screen()`` enqueues the screen and returns the (Q, B) keep mask.
+    Each step ends in the ``device_get(overflow)`` the loop needs anyway,
+    so its span holds its device work: ``repro.engine.screen`` the screen
+    (and any radius tightening) with the first compaction,
+    ``repro.engine.escalate`` each further one."""
+    with span("repro.engine.screen", cap=cap):
+        keep = screen()
+        idx, valid, overflow = _compact_mask(keep, cap)
+        done = cap >= B or not bool(jax.device_get(overflow).any())
+    for _ in range(max_doublings):
+        if done:
+            break
+        cap = min(B, cap * 4)
+        with span("repro.engine.escalate", cap=cap):
+            idx, valid, overflow = _compact_mask(keep, cap)
+            done = cap >= B or not bool(jax.device_get(overflow).any())
+    return idx, valid, overflow
 
 
 def _coerce_quant_options(options, legacy: dict):
@@ -1779,13 +1805,10 @@ def quantized_range_query(
     capacity, max_doublings = opts.capacity, opts.max_doublings
     Q, B = qr.q.shape[0], tindex.size
     eps = _eps_qcol(epsilon, Q)
-    keep, _ = _quantized_screen_backend(tindex, qr, eps, opts.backend)
     cap = min(B, 64 if capacity is None else max(1, int(capacity)))
-    for _ in range(max_doublings + 1):
-        idx, valid, overflow = _compact_mask(keep, cap)
-        if cap >= B or not bool(jax.device_get(overflow).any()):
-            break
-        cap = min(B, cap * 4)
+    idx, valid, overflow = _compact_escalating(
+        lambda: _quantized_screen_backend(tindex, qr, eps, opts.backend)[0],
+        cap, B, max_doublings)
     d2 = _verify_tier(tindex.raw, idx, qr.q, valid, opts)
     answer = valid & (d2 <= eps * eps)
     return idx, answer, jnp.where(answer, d2, jnp.inf), ~overflow
@@ -1817,8 +1840,9 @@ def _tiered_seed_eps(tindex: TieredIndex, qr: QueryReprDev,
         return jnp.zeros((qr.q.shape[0], 1), jnp.float32)
     S = min(R, max(k, _KNN_SEED_SAMPLE))
     sample = (np.arange(S) * R) // S
-    rows = jnp.asarray(np.asarray(tindex.raw[sample]), jnp.float32)
-    return _sample_eps(rows, qr.q, k)
+    with span("repro.engine.seed", rows=S):
+        rows = jnp.asarray(np.asarray(tindex.raw[sample]), jnp.float32)
+        return _sample_eps(rows, qr.q, k)
 
 
 def quantized_knn_query(
@@ -1849,16 +1873,16 @@ def quantized_knn_query(
     Q, B = qr.q.shape[0], tindex.size
     k_eff = min(int(k), B)
     eps = _slacked(_tiered_seed_eps(tindex, qr, k_eff))      # (Q, 1)
-    keep, d2hat = _quantized_screen_backend(tindex, qr, eps, opts.backend)
-    keep = _tighten_tiered_keep(tindex.dev, qr.q, keep, d2hat, eps,
-                                jnp.ones((Q, 1), bool), k_eff)
+
+    def screen():
+        keep, d2hat = _quantized_screen_backend(tindex, qr, eps,
+                                                opts.backend)
+        return _tighten_tiered_keep(tindex.dev, qr.q, keep, d2hat, eps,
+                                    jnp.ones((Q, 1), bool), k_eff)
+
     cap = min(B, max(4 * k_eff, 64) if capacity is None else int(capacity))
-    cap = max(cap, k_eff)
-    for _ in range(max_doublings + 1):
-        idx, valid, overflow = _compact_mask(keep, cap)
-        if cap >= B or not bool(jax.device_get(overflow).any()):
-            break
-        cap = min(B, cap * 4)
+    idx, valid, overflow = _compact_escalating(screen, max(cap, k_eff), B,
+                                               max_doublings)
     d2 = _verify_tier(tindex.raw, idx, qr.q, valid, opts)
     neg, pos = jax.lax.top_k(-d2, k_eff)                     # ascending d2
     nn_d2 = -neg
@@ -1896,17 +1920,18 @@ def quantized_mixed_query(
     eps_req = _eps_qcol(epsilon, Q)
     eps = jnp.where(knn_col, _slacked(_tiered_seed_eps(tindex, qr, k_eff)),
                     eps_req)
-    keep, d2hat = _quantized_screen_backend(tindex, qr, eps, opts.backend)
-    if np.asarray(is_knn).any():          # range-only batches keep ε as is
-        keep = _tighten_tiered_keep(tindex.dev, qr.q, keep, d2hat, eps,
-                                    knn_col, k_eff)
+
+    def screen():
+        keep, d2hat = _quantized_screen_backend(tindex, qr, eps,
+                                                opts.backend)
+        if np.asarray(is_knn).any():      # range-only batches keep ε as is
+            keep = _tighten_tiered_keep(tindex.dev, qr.q, keep, d2hat, eps,
+                                        knn_col, k_eff)
+        return keep
+
     cap = min(B, max(4 * k_eff, 64) if capacity is None else int(capacity))
-    cap = max(cap, k_eff)
-    for _ in range(max_doublings + 1):
-        idx, valid, overflow = _compact_mask(keep, cap)
-        if cap >= B or not bool(jax.device_get(overflow).any()):
-            break
-        cap = min(B, cap * 4)
+    idx, valid, overflow = _compact_escalating(screen, max(cap, k_eff), B,
+                                               max_doublings)
     d2 = _verify_tier(tindex.raw, idx, qr.q, valid, opts)
     answer = jnp.where(knn_col, valid, valid & (d2 <= eps_req * eps_req))
     return idx, answer, jnp.where(answer, d2, jnp.inf), overflow

@@ -36,6 +36,7 @@ import numpy as np
 from ..core import representation as repr_registry
 from ..core.fastsax import FastSAXConfig, FastSAXIndex, LevelData
 from ..core.representation import DEFAULT_STACK
+from ..obs.spans import span
 from ..runtime import chaos
 
 FORMAT_VERSION = 1
@@ -176,24 +177,26 @@ def gather_rows(raw, idx, key: str = "0") -> np.ndarray:
     they are masked downstream but must never fault the mmap read), the
     read passes through the ``verify_fetch`` chaos site, and a sheared /
     short read fails loudly instead of returning a silently truncated
-    candidate set.
+    candidate set.  Each call is one ``repro.engine.gather`` span.
     """
     n_rows = int(raw.shape[0])
     idx = np.asarray(idx)
-    if n_rows == 0:
-        # All-pad raw tier (e.g. a failover shard past ``n_valid``): every
-        # candidate slot is dead and masked downstream — serve zeros
-        # rather than fancy-indexing an empty mmap.
-        rows = np.zeros(idx.shape + tuple(raw.shape[1:]), np.float32)
-    else:
-        clamped = np.clip(idx, 0, max(n_rows - 1, 0))
-        rows = np.asarray(raw[clamped], dtype=np.float32)
-    # Chaos injection site "verify_fetch" (DESIGN.md §13): a truncate
-    # fault shears query rows *here*, between the mmap read and the shape
-    # check below, so a torn verify fetch is caught before any distance
-    # is computed from it.
-    rows = chaos.apply("verify_fetch", key, rows)
     want = idx.shape + tuple(raw.shape[1:])
+    with span("repro.engine.gather", rows=int(idx.size),
+              bytes=int(np.prod(want)) * 4):
+        if n_rows == 0:
+            # All-pad raw tier (e.g. a failover shard past ``n_valid``):
+            # every candidate slot is dead and masked downstream — serve
+            # zeros rather than fancy-indexing an empty mmap.
+            rows = np.zeros(want, np.float32)
+        else:
+            clamped = np.clip(idx, 0, max(n_rows - 1, 0))
+            rows = np.asarray(raw[clamped], dtype=np.float32)
+        # Chaos injection site "verify_fetch" (DESIGN.md §13): a truncate
+        # fault shears query rows *here*, between the mmap read and the
+        # shape check below, so a torn verify fetch is caught before any
+        # distance is computed from it.
+        rows = chaos.apply("verify_fetch", key, rows)
     if rows.shape != want:
         raise IOError(
             f"verify fetch (key={key!r}) returned shape {rows.shape} for "

@@ -570,8 +570,9 @@ def main(argv=None):
                     help="with --serve: write the load generator's "
                          "per-request JSONL to this file")
     ap.add_argument("--profile-dir", default="",
-                    help="with --trace: jax.profiler capture directory "
-                         "wrapped around each dispatch (XLA-level detail)")
+                    help="jax.profiler trace directory: one session from "
+                         "service start to stop, holding every repro.* "
+                         "span beside the device operations")
     args = ap.parse_args(argv)
     use_compile_cache()
     if args.serve:

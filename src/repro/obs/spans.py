@@ -1,4 +1,14 @@
-"""Structured tracing: a bounded span ring with JSONL / Chrome export.
+"""Structured tracing: one span primitive, on the profiler's clock, and a
+bounded span ring with JSONL / Chrome export.
+
+:func:`span` is the program's one way to time a block: it always opens a
+``jax.profiler.TraceAnnotation``, so whenever a profiler session is
+active (``ServeConfig.profile_dir``, or a caller's own
+``jax.profiler.trace``) the span lands in the device trace on the same
+clock as the device operations.  With no session active the annotation
+costs about a microsecond.  Given a ``recorder`` (the service's ring,
+``ServeConfig.trace``), the span is also appended to it.  Attributes are
+ints or strings already at hand: computing them must add no device sync.
 
 A :class:`SpanRecorder` is a fixed-capacity ``deque`` of closed spans —
 ``(name, t0, t1, attrs)`` on the ``time.perf_counter`` clock, the same
@@ -14,20 +24,17 @@ Exports:
   * :meth:`SpanRecorder.to_jsonl` — one span per line, machine-joinable;
   * :meth:`SpanRecorder.to_chrome_trace` — the Chrome trace-event JSON
     array (``chrome://tracing`` / Perfetto ``ph:"X"`` complete events,
-    microsecond timestamps);
-  * :func:`profiler_capture` — the opt-in ``jax.profiler`` capture
-    context the serving layer wraps around Pallas dispatches when a
-    profile directory is configured (XLA/TPU-level detail the host spans
-    cannot see).
+    microsecond timestamps).
 """
 from __future__ import annotations
 
 import collections
-import contextlib
 import dataclasses
 import json
 import threading
 import time
+
+import jax
 
 
 @dataclasses.dataclass
@@ -71,14 +78,9 @@ class SpanRecorder:
             self._ring.append(Span(name, float(t0), float(t1), attrs))
             self._recorded += 1
 
-    @contextlib.contextmanager
-    def span(self, name: str, **attrs):
-        """Time a block on the recorder's clock and record it on exit."""
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.record(name, t0, time.perf_counter(), **attrs)
+    def span(self, name: str, **attrs) -> "_OpenSpan":
+        """:func:`span` recording into this ring."""
+        return span(name, self, **attrs)
 
     def snapshot(self) -> list:
         with self._lock:
@@ -121,15 +123,38 @@ class SpanRecorder:
         return len(events)
 
 
-@contextlib.contextmanager
-def profiler_capture(logdir: str):
-    """Opt-in ``jax.profiler`` capture around a dispatch.  A no-op when
-    ``logdir`` is falsy, so call sites need no branching; the import is
-    deferred so the hook costs nothing unless actually engaged."""
-    if not logdir:
-        yield
-        return
-    import jax
+class _OpenSpan:
+    """The context manager :func:`span` returns; :meth:`set` adds
+    attributes known only once the block has started."""
 
-    with jax.profiler.trace(str(logdir)):
-        yield
+    __slots__ = ("name", "recorder", "attrs", "_ann", "_t0")
+
+    def __init__(self, name: str, recorder, attrs: dict):
+        self.name = name
+        self.recorder = recorder
+        self.attrs = attrs
+
+    def __enter__(self) -> "_OpenSpan":
+        self._ann = jax.profiler.TraceAnnotation(self.name, **self.attrs)
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def set(self, **attrs) -> None:
+        self.attrs.update(attrs)
+        self._ann.set_metadata(**attrs)
+
+    def __exit__(self, *exc) -> bool:
+        self._ann.__exit__(*exc)
+        if self.recorder is not None:
+            self.recorder.record(self.name, self._t0, time.perf_counter(),
+                                 **self.attrs)
+        return False
+
+
+def span(name: str, recorder: SpanRecorder | None = None,
+         **attrs) -> _OpenSpan:
+    """Time a block as a profiler annotation named ``name`` (with
+    ``attrs`` as its stats) and, given ``recorder``, as a ring span on
+    ``time.perf_counter``."""
+    return _OpenSpan(name, recorder, attrs)

@@ -122,6 +122,13 @@ class Request:
     #                                exclusion-zone parameters) — opaque to
     #                                the batcher, read by _postprocess hooks
     t_submit: float = 0.0
+    # Request times inside the program, all on time.perf_counter:
+    # ``t_dispatch`` is the start of the dispatch that carries the request
+    # and ``batch_seq`` that dispatch's sequence number (the ``seq`` of its
+    # ``repro.serve.batch`` span); ``t_done`` is stamped on resolve.
+    t_dispatch: Optional[float] = None
+    batch_seq: Optional[int] = None
+    t_done: float = 0.0
     status: str = ""
     ids: Optional[np.ndarray] = None
     distances: Optional[np.ndarray] = None
@@ -136,6 +143,7 @@ class Request:
         default_factory=threading.Event, repr=False)
 
     def _resolve(self, status: str, ids=None, distances=None, error=None):
+        self.t_done = time.perf_counter()
         self.status = status
         self.ids = ids
         self.distances = distances
@@ -176,9 +184,11 @@ class MicroBatcher:
         self.join_timeout_s = float(join_timeout_s)
         self.stats = stats or StatsTracker()
         # Optional obs.spans.SpanRecorder: when set, every formed batch
-        # records a "batch_form" span plus one "enqueue" span per member
-        # (t_submit -> formation — queueing + coalescing time).  None (the
-        # default) keeps the hot path span-free.
+        # records a "repro.batcher.form" span plus one
+        # "repro.batcher.enqueue" span per member (t_submit -> formation —
+        # queueing + coalescing time).  Ring only: they start in the past,
+        # so the profiler sees the wait through the attributes of the
+        # service's "repro.serve.batch" span instead.
         self.tracer = tracer
         self._queue: list = []
         self._cond = threading.Condition()
@@ -319,10 +329,10 @@ class MicroBatcher:
                 live.append(req)
         if self.tracer is not None and batch:
             t_first = min(r.t_submit for r in batch)
-            self.tracer.record("batch_form", t_first, now,
+            self.tracer.record("repro.batcher.form", t_first, now,
                                batch=len(live), expired=len(batch) - len(live))
             for req in live:
-                self.tracer.record("enqueue", req.t_submit, now,
+                self.tracer.record("repro.batcher.enqueue", req.t_submit, now,
                                    kind=req.kind)
         return live
 
@@ -353,8 +363,8 @@ class MicroBatcher:
                         "dispatch_fn returned without resolving request"))
                 for req in batch:
                     if req.status == OK:
-                        self.stats.on_served(
-                            time.perf_counter() - req.t_submit)
+                        self.stats.on_served(req.t_done - req.t_submit,
+                                             req.t_done)
             finally:
                 with self._cond:
                     self._in_flight = 0

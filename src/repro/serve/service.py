@@ -49,7 +49,7 @@ from ..core.engine import (DeviceIndex, build_device_index,
 from ..core.options import SearchOptions
 from ..core.representation import DEFAULT_STACK
 from ..obs.calibration import CalibrationLog
-from ..obs.spans import SpanRecorder, profiler_capture
+from ..obs.spans import SpanRecorder, span
 from ..obs.trace import select_queries, trace_totals
 from ..runtime import chaos
 from .batcher import (BREAKER_OPEN, FAILED, KIND_KNN, KIND_RANGE, OK,
@@ -99,7 +99,8 @@ class ServeConfig:
     trace: bool = False            # cascade counters + spans + calibration
     trace_ring: int = 4096         # span ring capacity (bounded memory)
     calibration_ring: int = 2048   # dispatch-record ring capacity
-    profile_dir: str = ""          # jax.profiler capture dir ("" = off)
+    profile_dir: str = ""          # jax.profiler trace dir: one session
+    #                                from start() to stop() ("" = off)
 
     @classmethod
     def from_options(cls, options: SearchOptions, **overrides):
@@ -126,6 +127,21 @@ def _pow2_at_least(n: int, cap: int) -> int:
 
 
 _DENSE = -1   # capacity-hint sentinel: this k bucket dispatches densely
+
+
+def _to_host(*outs):
+    """The one device→host copy every backend's outputs go through: wait
+    for the device first, so the ``repro.serve.d2h`` span times the copy
+    alone."""
+    jax.block_until_ready(outs)
+    with span("repro.serve.d2h", bytes=sum(int(a.nbytes) for a in outs)):
+        return tuple(np.asarray(a) for a in outs)
+
+
+def _counted(trace):
+    """A cascade-counter result already in hand, as the zero-argument
+    callable a backend's dispatch returns for it."""
+    return lambda: trace
 
 
 class _SingleBackend:
@@ -215,14 +231,19 @@ class _SingleBackend:
 
     def dispatch(self, q: np.ndarray, eps: np.ndarray, is_knn: np.ndarray,
                  k: int, want_trace: bool = False):
+        """One padded micro-batch: host ``(idx, answer, d2)`` and, when
+        ``want_trace``, a zero-argument callable that returns the batch's
+        ``QueryTrace`` (where counting is a device pass of its own, the
+        call runs it, so the caller can time it apart), else None."""
         B = self.size
-        qr = represent_queries(jnp.asarray(q, jnp.float32),
-                               self.index.levels, self.index.alphabet,
-                               normalize=self.cfg.normalize_queries,
-                               stack=tuple(getattr(self.index, "stack",
-                                                   DEFAULT_STACK)))
-        eps_j = jnp.asarray(eps, jnp.float32)
-        knn_j = jnp.asarray(is_knn)
+        with span("repro.serve.represent"):
+            qr = represent_queries(jnp.asarray(q, jnp.float32),
+                                   self.index.levels, self.index.alphabet,
+                                   normalize=self.cfg.normalize_queries,
+                                   stack=tuple(getattr(self.index, "stack",
+                                                       DEFAULT_STACK)))
+            eps_j = jnp.asarray(eps, jnp.float32)
+            knn_j = jnp.asarray(is_knn)
         self._note_demotion(k)
         trace = None
         fused = self.fused_call(k)
@@ -233,8 +254,8 @@ class _SingleBackend:
             # the XLA path.
             idx, answer, d2, overflow = fused(self.index, qr, eps_j, knn_j)
             if want_trace:
-                trace = mixed_trace(self.index, qr, eps_j, knn_j, k,
-                                    answer, d2)
+                trace = functools.partial(mixed_trace, self.index, qr, eps_j,
+                                          knn_j, k, answer, d2)
         else:
             idx = answer = d2 = overflow = None
             cap_limit = max(64, int(self.cfg.dense_fallback_frac * B))
@@ -251,6 +272,7 @@ class _SingleBackend:
                     idx, answer, d2, overflow, trace = mixed_query_and_trace(
                         self.index, qr, eps_j, knn_j, k, capacity=cap,
                         n_iters=self.cfg.n_iters)
+                    trace = _counted(trace)
                 else:
                     idx, answer, d2, overflow = mixed_query(
                         self.index, qr, eps_j, knn_j, k, capacity=cap,
@@ -267,11 +289,12 @@ class _SingleBackend:
                     idx, answer, d2, overflow, trace = \
                         mixed_query_dense_and_trace(
                             self.index, qr, eps_j, knn_j, k)
+                    trace = _counted(trace)
                 else:
                     idx, answer, d2, overflow = mixed_query_dense(
                         self.index, qr, eps_j, knn_j, k)
         self._note_certificates(overflow)
-        return np.asarray(idx), np.asarray(answer), np.asarray(d2), trace
+        return (*_to_host(idx, answer, d2), trace)
 
 
 class _QuantizedBackend:
@@ -331,14 +354,16 @@ class _QuantizedBackend:
                  k: int, want_trace: bool = False):
         from ..core.engine import quantized_mixed_query, quantized_mixed_trace
 
-        qr = represent_queries(jnp.asarray(q, jnp.float32),
-                               self.tindex.dev.levels,
-                               self.tindex.dev.alphabet,
-                               normalize=self.cfg.normalize_queries,
-                               stack=tuple(getattr(self.tindex.dev, "stack",
-                                                   DEFAULT_STACK)))
-        eps_j = jnp.asarray(eps, jnp.float32)
-        knn_j = jnp.asarray(is_knn)
+        with span("repro.serve.represent"):
+            qr = represent_queries(jnp.asarray(q, jnp.float32),
+                                   self.tindex.dev.levels,
+                                   self.tindex.dev.alphabet,
+                                   normalize=self.cfg.normalize_queries,
+                                   stack=tuple(getattr(self.tindex.dev,
+                                                       "stack",
+                                                       DEFAULT_STACK)))
+            eps_j = jnp.asarray(eps, jnp.float32)
+            knn_j = jnp.asarray(is_knn)
         cap = self._cap or self.cfg.capacity0 or max(4 * k, 64)
         idx, answer, d2, overflow = quantized_mixed_query(
             self.tindex, qr, eps_j, knn_j, k,
@@ -349,10 +374,10 @@ class _QuantizedBackend:
             bad = int(np.asarray(overflow).sum())
             total = int(np.asarray(overflow).size)
             self.stats.on_certificates(total - bad, total)
-        trace = (quantized_mixed_trace(self.tindex.dev, qr, eps_j, knn_j, k,
-                                       answer, d2)
+        trace = (functools.partial(quantized_mixed_trace, self.tindex.dev,
+                                   qr, eps_j, knn_j, k, answer, d2)
                  if want_trace else None)
-        return np.asarray(idx), np.asarray(answer), np.asarray(d2), trace
+        return (*_to_host(idx, answer, d2), trace)
 
 
 class _DistQuantizedBackend:
@@ -414,7 +439,7 @@ class _DistQuantizedBackend:
             bad = int(np.asarray(overflow).sum())
             total = int(np.asarray(overflow).size)
             self.stats.on_certificates(total - bad, total)
-        return np.asarray(gidx), np.asarray(answer), np.asarray(d2), None
+        return (*_to_host(gidx, answer, d2), None)
 
 
 class _ShardedBackend:
@@ -479,14 +504,16 @@ class _ShardedBackend:
                 self.stats.on_escalation()
             cap = min(b_loc, cap * 4)
         self._cap = max(cap, self._cap or 0)
-        gidx, answer, d2 = (np.asarray(gidx), np.asarray(answer),
-                            np.asarray(d2))
+        gidx, answer, d2 = _to_host(gidx, answer, d2)
         if self.stats is not None:
             # Per-query certificate: no shard's buffer truncated.
             bad = int(np.asarray(overflow).any(axis=-1).sum())
             self.stats.on_certificates(gidx.shape[0] - bad, gidx.shape[0])
-        trace = None
-        if want_trace:
+        if not want_trace:
+            return gidx, answer, d2, None
+        index = self.index
+
+        def count():
             # Each row's FINAL radius, recovered from the merged buffers
             # exactly like engine.mixed_trace (host arithmetic here; the
             # counting pass itself runs sharded with a psum merge).
@@ -497,14 +524,15 @@ class _ShardedBackend:
             eps_knn = np.where(np.isfinite(eps_knn), eps_knn, _SEED_EPS_MAX)
             eps_f = np.where(is_knn, eps_knn, eps).astype(np.float32)
             trace = distributed_cascade_trace(
-                self.index, q, eps_f, self.mesh, axis=self.axis,
+                index, q, eps_f, self.mesh, axis=self.axis,
                 normalize_queries=self.cfg.normalize_queries,
                 n_valid=self.n_valid)
             n_ans = np.isfinite(d2a).sum(axis=-1).astype(np.int32)
             answers = np.where(is_knn, np.minimum(n_ans, k_eff), n_ans)
-            trace = dataclasses.replace(trace,
-                                        answers=answers.astype(np.int32))
-        return gidx, answer, d2, trace
+            return dataclasses.replace(trace,
+                                       answers=answers.astype(np.int32))
+
+        return gidx, answer, d2, count
 
 
 class _FailoverBackend:
@@ -574,7 +602,7 @@ class _FailoverBackend:
             bad = int(np.asarray(overflow).sum()) if cov.exact \
                 else gidx.shape[0]
             self._stats.on_certificates(gidx.shape[0] - bad, gidx.shape[0])
-        return gidx, answer, d2, None
+        return (*_to_host(gidx, answer, d2), None)
 
 
 class SearchService:
@@ -614,6 +642,8 @@ class SearchService:
         # can never hit a cold (Q, k=1) jit entry at serve time.
         self._k_floor = _pow2_at_least(
             min(cfg.warmup_ks) if cfg.warmup_ks else 1, self.backend.size)
+        self._batch_seq = 0        # dispatches so far (repro.serve.batch seq)
+        self._profiling = False    # a cfg.profile_dir session is open
         self._loaded_gen = mutable.generation if mutable is not None else -1
         self._last_refresh = time.perf_counter()
         self._stale = False
@@ -764,11 +794,24 @@ class SearchService:
     # --- lifecycle ----------------------------------------------------------
 
     def start(self) -> "SearchService":
+        if self.cfg.profile_dir and not self._profiling:
+            # One profiler session for the service's run: the trace holds
+            # every repro.* span beside the device operations.
+            jax.profiler.start_trace(self.cfg.profile_dir)
+            self._profiling = True
         self._batcher.start()
         return self
 
+    def _stop_profiler(self):
+        if self._profiling:
+            self._profiling = False
+            jax.profiler.stop_trace()
+
     def stop(self):
-        self._batcher.stop()
+        try:
+            self._batcher.stop()
+        finally:
+            self._stop_profiler()
         if self._unsubscribe is not None:
             self._unsubscribe()
             self._unsubscribe = None
@@ -779,7 +822,10 @@ class SearchService:
         stop the dispatcher.  The SIGTERM path in ``launch/serve.py``
         calls this so preemption never drops an accepted request.
         Returns False if in-flight work did not finish in time."""
-        drained = self._batcher.drain(timeout_s=timeout_s)
+        try:
+            drained = self._batcher.drain(timeout_s=timeout_s)
+        finally:
+            self._stop_profiler()
         if self._unsubscribe is not None:
             self._unsubscribe()
             self._unsubscribe = None
@@ -836,8 +882,10 @@ class SearchService:
             for kb in sorted(set(k_buckets)):
                 is_knn = np.zeros(qb, dtype=bool)
                 is_knn[: max(1, qb // 2)] = True
-                self.backend.dispatch(q, eps, is_knn, kb,
-                                      want_trace=bool(self.cfg.trace))
+                count = self.backend.dispatch(
+                    q, eps, is_knn, kb, want_trace=bool(self.cfg.trace))[3]
+                if count is not None:
+                    count()
         return self
 
     # --- submission ---------------------------------------------------------
@@ -983,6 +1031,13 @@ class SearchService:
 
     def _dispatch(self, batch: list):
         """MicroBatcher callback: one padded, bucketed device pass."""
+        t0 = time.perf_counter()
+        self._batch_seq += 1
+        with span("repro.serve.batch", self.tracer,
+                  seq=self._batch_seq) as batch_span:
+            self._dispatch_batch(batch, t0, batch_span)
+
+    def _dispatch_batch(self, batch: list, t0: float, batch_span):
         self._maybe_refresh()
         if not self.breaker.allow():
             # Breaker open: shed the whole batch with a *rejected* status
@@ -997,48 +1052,59 @@ class SearchService:
             self.stats.set_breaker(self.breaker.state,
                                    self.breaker.state_code)
             return
-        Q = len(batch)
-        qb = _pow2_at_least(Q, self.cfg.max_batch)
-        n = self.backend.n
-        q = np.empty((qb, n), dtype=np.float32)
-        eps = np.zeros(qb, dtype=np.float32)
-        is_knn = np.zeros(qb, dtype=bool)
-        max_k = 1
-        for i, req in enumerate(batch):
-            if req.query.shape != (n,):
-                req._resolve(FAILED, error=ValueError(
-                    f"query must be ({n},), got {req.query.shape}"))
-                self.stats.on_failed()
-                continue
-            q[i] = req.query
-            if req.kind == KIND_KNN:
-                is_knn[i] = True
-                max_k = max(max_k, req.k)
-            else:
-                eps[i] = req.epsilon
-        live = [(i, r) for i, r in enumerate(batch)
-                if not r._done.is_set()]
-        if not live:
-            return
-        # Padding rows replay the first live query as a range query at
-        # ε = 0 — same shapes, negligible extra work, no effect on answers.
-        for j in range(Q, qb):
-            q[j] = q[live[0][0]]
-        k_bucket = _pow2_at_least(max(max_k, self._k_floor),
-                                  self.backend.size)
+        tracer = self.tracer
+        with span("repro.serve.assemble", tracer):
+            Q = len(batch)
+            qb = _pow2_at_least(Q, self.cfg.max_batch)
+            n = self.backend.n
+            q = np.empty((qb, n), dtype=np.float32)
+            eps = np.zeros(qb, dtype=np.float32)
+            is_knn = np.zeros(qb, dtype=bool)
+            max_k = 1
+            for i, req in enumerate(batch):
+                if req.query.shape != (n,):
+                    req._resolve(FAILED, error=ValueError(
+                        f"query must be ({n},), got {req.query.shape}"))
+                    self.stats.on_failed()
+                    continue
+                q[i] = req.query
+                if req.kind == KIND_KNN:
+                    is_knn[i] = True
+                    max_k = max(max_k, req.k)
+                else:
+                    eps[i] = req.epsilon
+            live = [(i, r) for i, r in enumerate(batch)
+                    if not r._done.is_set()]
+            if not live:
+                return
+            # Padding rows replay the first live query as a range query at
+            # ε = 0 — same shapes, negligible extra work, no effect on
+            # answers.
+            for j in range(Q, qb):
+                q[j] = q[live[0][0]]
+            k_bucket = _pow2_at_least(max(max_k, self._k_floor),
+                                      self.backend.size)
+            for _, req in live:
+                req.t_dispatch = t0
+                req.batch_seq = self._batch_seq
+        waits = [t0 - req.t_submit for _, req in live]
+        batch_span.set(live=len(live), qb=qb, kb=k_bucket,
+                       wait_ms_max=round(max(waits) * 1e3, 3),
+                       wait_ms_sum=round(sum(waits) * 1e3, 3))
         self.stats.on_batch(len(live), qb, self._batcher.depth)
-        tracing = self.tracer is not None
+        tracing = tracer is not None
         # Hold the refresh lock across dispatch + ids snapshot: a
         # concurrent refresh() must not swap in a new generation's ids
         # between the device pass and the id mapping.
         try:
             with self._refresh_lock:
-                t0 = time.perf_counter()
                 chaos.maybe_fire("serve_dispatch")
-                with profiler_capture(self.cfg.profile_dir):
-                    idx, answer, d2, trace = self.backend.dispatch(
+                with span("repro.serve.device", tracer, qb=qb,
+                          kb=k_bucket):
+                    t_dev = time.perf_counter()
+                    idx, answer, d2, count = self.backend.dispatch(
                         q, eps, is_knn, k_bucket, want_trace=tracing)
-                t1 = time.perf_counter()
+                    t_dev = time.perf_counter() - t_dev
                 ids = self._ids
                 coverage = getattr(self.backend, "last_coverage", None)
         except BaseException:
@@ -1051,30 +1117,26 @@ class SearchService:
         self.breaker.on_success()
         self.stats.set_breaker(self.breaker.state, self.breaker.state_code)
         if tracing:
-            # The dispatch outputs are host numpy already (the backends
-            # materialise them), so t1 − t0 covers the full device pass —
-            # no extra sync was added to measure it.
-            self.tracer.record("dispatch", t0, t1, batch=len(live),
-                               bucket=qb, k=k_bucket)
+            # The dispatch outputs are host numpy (every backend returns
+            # through _to_host), so t_dev covers the full device pass and
+            # the copy — no extra sync was added to measure it.
             self.calibration.record(
                 batch=len(live), k=k_bucket,
                 backend=type(self.backend).__name__,
-                measured_s=t1 - t0,
+                measured_s=t_dev,
                 estimate=self.backend.cost_estimate(qb, k_bucket))
-            if trace is not None:
-                with self.tracer.span("verify", batch=len(live)):
-                    live_trace = select_queries(trace,
-                                                [i for i, _ in live])
-                    totals = trace_totals(live_trace, self.backend.size)
-                    totals.update(self.backend.trace_bytes(live_trace))
-                    self.stats.on_cascade(totals)
-            with self.tracer.span("reply", batch=len(live)):
-                for i, req in live:
-                    self._finish(req, idx[i], answer[i], d2[i], ids,
-                                 coverage)
-            return
-        for i, req in live:
-            self._finish(req, idx[i], answer[i], d2[i], ids, coverage)
+        if count is not None:
+            with span("repro.serve.cascade_count", tracer,
+                      batch=len(live)):
+                live_trace = select_queries(count(), [i for i, _ in live])
+                totals = trace_totals(live_trace, self.backend.size)
+                totals.update(self.backend.trace_bytes(live_trace))
+                self.stats.on_cascade(totals)
+        n_knn = sum(req.kind == KIND_KNN for _, req in live)
+        with span("repro.serve.reply", tracer, knn=n_knn,
+                  range=len(live) - n_knn):
+            for i, req in live:
+                self._finish(req, idx[i], answer[i], d2[i], ids, coverage)
 
     def _finish(self, req: Request, idx_row, answer_row, d2_row, ids_map,
                 coverage=None):

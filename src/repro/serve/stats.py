@@ -28,6 +28,7 @@ import time
 import numpy as np
 
 _RING = 8192   # latency / occupancy samples kept for percentile estimation
+QPS_WINDOW_S = 10.0   # qps counts the answers of this trailing window
 
 # Cascade accumulator keys — fixed so the snapshot (and the Prometheus
 # families built from it) exposes clean zeros before the first traced
@@ -46,13 +47,17 @@ class StatsTracker:
     (``escalations``, ``demotions``, certificate outcomes).  Gauges: queue
     depth (sampled at every batch formation), batch occupancy (actual
     requests / padded bucket slots — the cost of shape bucketing).  Latency
-    is measured submit→result per request, in seconds, and reported as
-    p50/p95/p99 ms.
+    is each request's own submit → resolve stamps (``Request.t_submit``,
+    ``Request.t_done``), reported as p50/p95/p99 ms; ``qps`` is the
+    answers of the trailing :data:`QPS_WINDOW_S` seconds over that window
+    (over the uptime while it is shorter).  ``clock`` is the clock those
+    stamps are on.
     """
 
-    def __init__(self):
+    def __init__(self, clock=time.perf_counter):
         self._lock = threading.Lock()
-        self.t_start = time.perf_counter()
+        self._clock = clock
+        self.t_start = clock()
         self.submitted = 0
         self.served = 0
         self.rejected_queue_full = 0
@@ -78,6 +83,7 @@ class StatsTracker:
         self.breaker_state_code = 0
         self.cascade = collections.Counter({k: 0 for k in CASCADE_KEYS})
         self._latency = collections.deque(maxlen=_RING)
+        self._answered = collections.deque()   # answer times, last window
         self._occupancy = collections.deque(maxlen=_RING)
         self._queue_depth = collections.deque(maxlen=_RING)
 
@@ -105,10 +111,17 @@ class StatsTracker:
             self._occupancy.append(n_requests / max(1, bucket_slots))
             self._queue_depth.append(queue_depth)
 
-    def on_served(self, latency_s: float):
+    def on_served(self, latency_s: float, t_done: float):
         with self._lock:
             self.served += 1
             self._latency.append(latency_s)
+            self._answered.append(t_done)
+            self._expire(t_done)
+
+    def _expire(self, now: float):
+        """Drop answer times older than the qps window (lock held)."""
+        while self._answered and self._answered[0] < now - QPS_WINDOW_S:
+            self._answered.popleft()
 
     def on_escalation(self, n: int = 1):
         with self._lock:
@@ -169,7 +182,10 @@ class StatsTracker:
             lat = np.asarray(self._latency, dtype=np.float64) * 1e3
             occ = np.asarray(self._occupancy, dtype=np.float64)
             depth = np.asarray(self._queue_depth, dtype=np.float64)
-            elapsed = time.perf_counter() - self.t_start
+            now = self._clock()
+            elapsed = now - self.t_start
+            self._expire(now)
+            window = min(elapsed, QPS_WINDOW_S)
             rejected = (self.rejected_queue_full + self.rejected_deadline
                         + self.shed)
             denom = max(1, self.submitted)
@@ -184,7 +200,8 @@ class StatsTracker:
                 "breaker_state_code": self.breaker_state_code,
                 "batches": self.batches,
                 "elapsed_s": round(elapsed, 3),
-                "qps": round(self.served / elapsed, 1) if elapsed > 0 else 0.0,
+                "qps": round(len(self._answered) / window, 1)
+                if window > 0 else 0.0,
                 "reject_rate": round(rejected / denom, 6),
                 "failure_rate": round(self.failed / denom, 6),
                 "mean_batch_size":

@@ -17,9 +17,14 @@ The load-bearing claims, each asserted here:
   * **metrics surface** — every REQUIRED_FAMILIES family renders, with
     clean zeros before traffic;
   * **traced serving** — a ``trace=True`` service answers identically to
-    the direct path and populates the cascade/span/calibration surfaces.
+    the direct path and populates the cascade/span/calibration surfaces;
+  * **program spans** — the ``repro.serve.*`` / ``repro.engine.*`` spans
+    land in a ``jax.profiler`` trace, nested where the work happens, with
+    the batch's shapes and counts as attributes.
 """
+import glob
 import json
+import os
 
 import numpy as np
 import pytest
@@ -297,6 +302,22 @@ def test_span_ring_bounded_and_exports(tmp_path):
     assert rec.counts() == {"dispatch": 8}
 
 
+def test_span_records_into_ring_with_late_attributes():
+    from repro.obs.spans import span
+
+    rec = SpanRecorder(capacity=8)
+    with span("repro.test.outer", rec, seq=1) as sp:
+        with span("repro.test.unrecorded"):
+            pass
+        sp.set(live=3)
+    with rec.span("repro.test.method", n=2):
+        pass
+    got = {s.name: s.attrs for s in rec.snapshot()}
+    assert got == {"repro.test.outer": {"seq": 1, "live": 3},
+                   "repro.test.method": {"n": 2}}
+    assert all(s.t1 >= s.t0 for s in rec.snapshot())
+
+
 def test_calibration_log_bounded_and_summary(tmp_path):
     log = CalibrationLog(capacity=4)
     assert log.summary()["n"] == 0            # clean zeros before traffic
@@ -355,7 +376,8 @@ def test_traced_service_exact_and_surfaces_populated(hidx):
         assert cascade["bytes_screen"] > 0 and cascade["bytes_verify"] > 0
         assert svc.tracer is not None and svc.tracer.recorded > 0
         names = set(svc.tracer.counts())
-        assert {"enqueue", "batch_form", "dispatch", "reply"} <= names
+        assert {"repro.batcher.enqueue", "repro.batcher.form",
+                "repro.serve.device", "repro.serve.reply"} <= names
         assert svc.calibration.recorded > 0
         text = svc.metrics_text()
     for fam in REQUIRED_FAMILIES:
@@ -410,3 +432,185 @@ def test_cli_info_stats_key_only_with_flag(tmp_path, capsys):
     for key in ("candidates", "excluded_c9", "excluded_c10", "answers",
                 "ops", "model_latency"):
         assert key in stats
+
+
+# ---------------------------------------------------------------------------
+# Program spans in the profiler's trace.
+# ---------------------------------------------------------------------------
+
+def _profiled(run, logdir):
+    """Run ``run()`` under one ``jax.profiler`` session and return the
+    ``repro.*`` host spans it recorded: [(name, start_ns, end_ns, stats)]
+    in start order."""
+    from jax.profiler import ProfileData
+
+    with jax.profiler.trace(str(logdir)):
+        run()
+    (path,) = glob.glob(os.path.join(str(logdir), "plugins", "profile", "*",
+                                     "*.xplane.pb"))
+    data = ProfileData.from_file(path)
+    spans = [(e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+             for plane in data.planes if plane.name.startswith("/host")
+             for line in plane.lines for e in line.events
+             if e.name.startswith("repro.")]
+    return sorted(spans, key=lambda sp: sp[1])
+
+
+def _named(spans, name):
+    return [sp for sp in spans if sp[0] == name]
+
+
+def _inside(child, parent):
+    return parent[1] <= child[1] and child[2] <= parent[2]
+
+
+def _serve_one_batch(svc, reqs):
+    """Start ``svc`` with ``reqs`` already queued, so they form one batch,
+    and wait for every answer; the backend's outputs are recorded."""
+    outs = []
+    dispatch = svc.backend.dispatch
+
+    def recording(*args, **kw):
+        out = dispatch(*args, **kw)
+        outs.append(out)
+        return out
+
+    svc.backend.dispatch = recording
+
+    def run():
+        with svc:
+            for r in reqs:
+                assert r.wait(120) == OK
+    return run, outs
+
+
+def test_serve_spans_nest_with_batch_attributes(hidx, tmp_path):
+    db, _ = hidx
+    svc = SearchService.from_series(
+        db, ServeConfig(max_batch=8, max_wait_ms=1.0,
+                        normalize_queries=False), normalize=False)
+    svc.warmup(qs=[4])
+    qs = make_queries(db, 3, seed=21)
+    reqs = [svc.submit_knn(qs[0], 3), svc.submit_knn(qs[1], 3),
+            svc.submit_range(qs[2], 2.0)]
+    run, outs = _serve_one_batch(svc, reqs)
+    spans = _profiled(run, tmp_path)
+    (batch,) = _named(spans, "repro.serve.batch")
+    assert batch[3]["live"] == 3 and batch[3]["qb"] == 4
+    assert batch[3]["kb"] == 8            # k 3 bucketed at the warmed floor
+    assert batch[3]["wait_ms_max"] >= 0.0
+    assert batch[3]["wait_ms_sum"] >= batch[3]["wait_ms_max"]
+    (assemble,) = _named(spans, "repro.serve.assemble")
+    (device,) = _named(spans, "repro.serve.device")
+    (reply,) = _named(spans, "repro.serve.reply")
+    for child in (assemble, device, reply):
+        assert _inside(child, batch)
+    assert assemble[2] <= device[1] and device[2] <= reply[1]
+    assert (device[3]["qb"], device[3]["kb"]) == (4, 8)
+    assert (reply[3]["knn"], reply[3]["range"]) == (2, 1)
+    (represent,) = _named(spans, "repro.serve.represent")
+    (d2h,) = _named(spans, "repro.serve.d2h")
+    assert _inside(represent, device) and _inside(d2h, device)
+    assert d2h[3]["bytes"] == sum(a.nbytes for a in outs[0][:3])
+    assert not _named(spans, "repro.serve.cascade_count")   # untraced
+    for r in reqs:
+        assert r.batch_seq == batch[3]["seq"]
+        assert r.t_submit <= r.t_dispatch <= r.t_done
+
+
+def test_tiered_spans_show_escalation_and_gather(hidx, tmp_path):
+    db, _ = hidx
+    svc = SearchService.from_series(
+        db, ServeConfig(quantization="int8", capacity0=4, max_batch=8,
+                        max_wait_ms=1.0, normalize_queries=False),
+        normalize=False)
+    qs = make_queries(db, 2, seed=22)
+    # ε far beyond every row: the screen keeps the whole database, so the
+    # first capacity overflows and the compaction escalates up to B.
+    reqs = [svc.submit_range(qs[0], 1e3), svc.submit_knn(qs[1], 3)]
+    run, outs = _serve_one_batch(svc, reqs)
+    spans = _profiled(run, tmp_path)
+    (batch,) = _named(spans, "repro.serve.batch")
+    (device,) = _named(spans, "repro.serve.device")
+    qb, slots = batch[3]["qb"], outs[0][0].shape[-1]
+    (seed,) = _named(spans, "repro.engine.seed")
+    (screen,) = _named(spans, "repro.engine.screen")
+    escalate = _named(spans, "repro.engine.escalate")
+    assert escalate
+    assert screen[3]["cap"] == max(4, batch[3]["kb"])
+    caps = [screen[3]["cap"]] + [sp[3]["cap"] for sp in escalate]
+    assert all(b == min(B, 4 * a) for a, b in zip(caps, caps[1:]))
+    assert caps[-1] == slots == B
+    (gather,) = _named(spans, "repro.engine.gather")
+    assert gather[3]["rows"] == qb * slots
+    assert gather[3]["bytes"] == qb * slots * N * 4
+    (verify,) = _named(spans, "repro.engine.verify")
+    order = [seed, screen, *escalate, gather, verify]
+    assert all(_inside(sp, device) for sp in order)
+    assert all(a[2] <= b[1] for a, b in zip(order, order[1:]))
+
+
+@pytest.mark.parametrize("quantization", ["none", "int8"])
+def test_cascade_count_runs_outside_device_span(hidx, tmp_path,
+                                                quantization):
+    db, _ = hidx
+    svc = SearchService.from_series(
+        db, ServeConfig(quantization=quantization, max_batch=8,
+                        max_wait_ms=1.0, normalize_queries=False,
+                        trace=True), normalize=False)
+    qs = make_queries(db, 2, seed=23)
+    reqs = [svc.submit_range(qs[0], 2.0), svc.submit_knn(qs[1], 3)]
+    run, _ = _serve_one_batch(svc, reqs)
+    spans = _profiled(run, tmp_path)
+    (device,) = _named(spans, "repro.serve.device")
+    (count,) = _named(spans, "repro.serve.cascade_count")
+    (reply,) = _named(spans, "repro.serve.reply")
+    (batch,) = _named(spans, "repro.serve.batch")
+    assert device[2] <= count[1] and count[2] <= reply[1]
+    assert _inside(count, batch)
+    assert svc.stats.snapshot()["cascade"]["queries"] == len(reqs)
+    ring = [sp.name for sp in svc.tracer.snapshot()]
+    assert ring.index("repro.serve.device") < \
+        ring.index("repro.serve.cascade_count") < \
+        ring.index("repro.serve.reply")
+
+
+def test_profile_dir_is_one_session_per_service_run(hidx, tmp_path):
+    from jax.profiler import ProfileData
+
+    db, _ = hidx
+    svc = SearchService.from_series(
+        db, ServeConfig(max_batch=8, max_wait_ms=1.0,
+                        normalize_queries=False,
+                        profile_dir=str(tmp_path)), normalize=False)
+    qs = make_queries(db, 2, seed=24)
+    with svc:
+        for q in qs:                    # one batch after the other
+            svc.knn(q, 3)
+    paths = glob.glob(os.path.join(str(tmp_path), "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    assert len(paths) == 1
+    data = ProfileData.from_file(paths[0])
+    seqs = sorted(dict(e.stats)["seq"] for plane in data.planes
+                  for line in plane.lines for e in line.events
+                  if e.name == "repro.serve.batch")
+    assert seqs == [1, 2]
+
+
+def test_stats_qps_counts_a_trailing_window():
+    from repro.serve.stats import QPS_WINDOW_S, StatsTracker
+
+    now = [0.0]
+    st = StatsTracker(clock=lambda: now[0])
+    for t in (0.1, 0.2, 0.3, 0.4, 0.5):
+        st.on_served(0.01, t)
+    now[0] = 1.0
+    assert st.snapshot()["qps"] == 5.0          # uptime shorter than window
+    for t in np.arange(1.0, 30.0, 0.5):         # 2 answers a second
+        st.on_served(0.01, float(t))
+    now[0] = 30.0
+    snap = st.snapshot()
+    assert snap["qps"] == 2.0                   # the last window only
+    assert snap["served"] == 5 + 58
+    now[0] = 30.0 + 2 * QPS_WINDOW_S            # idle since: no answers
+    assert st.snapshot()["qps"] == 0.0
