@@ -1527,6 +1527,35 @@ def _tighten_keep(qindex: QuantizedDeviceIndex, keep, d2hat, eps, radius,
     return jnp.where(knn_col, keep & (d2hat <= thresh * thresh), keep)
 
 
+def _kth_smallest_bisect(x: jnp.ndarray, k: int) -> jnp.ndarray:
+    """:func:`_kth_smallest` of a wide (Q, M) float32 array without a
+    sort: a bisection over the order-preserving int32 image of the bit
+    patterns (negative floats have their magnitude bits flipped, so -0.0
+    sorts just below +0.0, as in ``lax.top_k``).  Each of the 32 passes
+    counts the entries at or below the midpoint; the loop ends at the
+    smallest pattern with at least k entries at or below it, which is
+    the k-th smallest entry itself — bit for bit, ``+inf`` and ties
+    included (tests/test_selection.py).  32 reads of (Q, M) in place of
+    a sort over M; NaN-free input."""
+    flip = jnp.int32(0x7FFFFFFF)
+    bits = jax.lax.bitcast_convert_type(x, jnp.int32)
+    keys = jnp.where(bits < 0, bits ^ flip, bits)
+    lo = jnp.full((x.shape[0], 1), jnp.iinfo(jnp.int32).min, jnp.int32)
+    hi = jnp.full((x.shape[0], 1), jnp.iinfo(jnp.int32).max, jnp.int32)
+
+    def halve(_, bounds):
+        lo, hi = bounds
+        mid = (lo >> 1) + (hi >> 1) + (lo & hi & 1)      # floor mean, no overflow
+        at_most = jnp.sum(keys <= mid, axis=-1, keepdims=True,
+                          dtype=jnp.int32)
+        enough = at_most >= k
+        return jnp.where(enough, lo, mid + 1), jnp.where(enough, mid, hi)
+
+    key, _ = jax.lax.fori_loop(0, 32, halve, (lo, hi))
+    return jax.lax.bitcast_convert_type(
+        jnp.where(key < 0, key ^ flip, key), jnp.float32)
+
+
 @functools.partial(jax.jit, static_argnames=("k",))
 def _tighten_tiered_keep(qindex: QuantizedDeviceIndex, q, keep, d2hat, eps,
                          knn_col, k: int):
@@ -1535,21 +1564,83 @@ def _tighten_tiered_keep(qindex: QuantizedDeviceIndex, q, keep, d2hat, eps,
     rows is more candidates than the raw-tier verify can gather.  Any k
     distinct rows bound the k-th neighbour distance by their largest
     upper bound, so the k-th smallest :func:`_screen_upper_bounds`
-    (slacked) is a sound radius, within about 2·e_u of the true one."""
-    radius = _slacked(_kth_smallest(_screen_upper_bounds(qindex, q, d2hat),
-                                    k))
+    (slacked) is a sound radius, within about 2·e_u of the true one.
+    The bounds span the whole database axis, where a sort would cost
+    several times the screen, so the k-th comes by bisection."""
+    radius = _slacked(_kth_smallest_bisect(
+        _screen_upper_bounds(qindex, q, d2hat), k))
     return _tighten_keep(qindex, keep, d2hat, eps, radius, knn_col)
+
+
+@jax.jit
+def _survivor_prefix(keep: jnp.ndarray):
+    """Counting half of :func:`_compact_mask`: the (Q, B) keep mask packed
+    32 positions to a word (bit b of word w is position 32·w + b, the
+    tail padded unset), the inclusive per-word prefix count of set
+    positions, and the batch's largest survivor count (a scalar: all the
+    host needs to choose a capacity)."""
+    Q, B = keep.shape
+    W = -(-B // 32)
+    bits = jnp.pad(keep, ((0, 0), (0, 32 * W - B))).reshape(Q, W, 32)
+    words = jnp.sum(bits.astype(jnp.uint32)
+                    << jnp.arange(32, dtype=jnp.uint32), axis=-1,
+                    dtype=jnp.uint32)
+    prefix = jnp.cumsum(jax.lax.population_count(words).astype(jnp.int32),
+                        axis=-1)
+    return words, prefix, jnp.max(prefix[:, -1])
+
+
+def _popcount(w: jnp.ndarray) -> jnp.ndarray:
+    return jax.lax.population_count(w).astype(jnp.int32)
+
+
+@functools.partial(jax.jit, static_argnames=("capacity",))
+def _compact_counted(words: jnp.ndarray, prefix: jnp.ndarray, capacity: int):
+    """Selecting half of :func:`_compact_mask`, from
+    :func:`_survivor_prefix`'s words and prefix count.  Slot j < count
+    holds the first position whose running count of set positions
+    reaches j + 1; slot j ≥ count the first whose running count of UNSET
+    positions reaches j − count + 1 (the order ``lax.top_k`` documents
+    for the ties at key 0 of the keys ``B − i``).  A binary
+    search over the monotone per-word prefix finds the word, a second
+    over popcounts of its low bits the bit: about log2(B) steps of a
+    (Q, C) gather or bit count, and no sort."""
+    Q, W = words.shape
+    count = prefix[:, -1:]
+    j = jnp.arange(capacity, dtype=jnp.int32)[None, :]
+    valid = j < count
+    rank = jnp.where(valid, j + 1, j - count + 1)            # (Q, C), ≥ 1
+    lo = jnp.zeros((Q, capacity), jnp.int32)
+    hi = jnp.full((Q, capacity), W - 1, jnp.int32)
+    for _ in range((W - 1).bit_length()):
+        mid = (lo + hi) // 2
+        upto = jnp.take_along_axis(prefix, mid, axis=1)
+        reached = jnp.where(valid, upto, 32 * (mid + 1) - upto) >= rank
+        hi = jnp.where(reached, mid, hi)
+        lo = jnp.where(reached, lo, mid + 1)
+    word = jnp.take_along_axis(words, lo, axis=1)
+    before = jnp.take_along_axis(prefix, lo, axis=1) - _popcount(word)
+    rank = rank - jnp.where(valid, before, 32 * lo - before)  # 1..32
+    word = jnp.where(valid, word, ~word)
+    blo = jnp.zeros_like(lo)
+    bhi = jnp.full_like(lo, 31)
+    for _ in range(5):
+        mid = (blo + bhi) // 2
+        low = word & ((jnp.uint32(2) << mid.astype(jnp.uint32)) - 1)
+        reached = _popcount(low) >= rank
+        bhi = jnp.where(reached, mid, bhi)
+        blo = jnp.where(reached, blo, mid + 1)
+    return 32 * lo + blo, valid, count[:, 0] > capacity
 
 
 @functools.partial(jax.jit, static_argnames=("capacity",))
 def _compact_mask(keep: jnp.ndarray, capacity: int):
     """Low-index compaction of a dense keep mask (no distances needed):
-    (idx (Q, C), valid (Q, C), overflow (Q,))."""
-    B = keep.shape[-1]
-    keys = jnp.where(keep, B - jnp.arange(B, dtype=jnp.int32)[None, :], 0)
-    top, idx = jax.lax.top_k(keys, capacity)
-    valid = top > 0
-    return idx, valid, keep.sum(axis=-1) > capacity
+    (idx (Q, C), valid (Q, C), overflow (Q,)).  Slots past a row's count
+    hold its lowest unset positions, ascending.  Counting, not sorting:
+    :func:`_survivor_prefix` then :func:`_compact_counted`."""
+    words, prefix, _ = _survivor_prefix(keep)
+    return _compact_counted(words, prefix, capacity)
 
 
 @jax.jit
@@ -1746,28 +1837,35 @@ def _verify_tier(raw, idx, q, valid, opts: SearchOptions,
         return _verify_gathered(jnp.asarray(rows), q, valid)
 
 
-def _compact_escalating(screen, cap: int, B: int, max_doublings: int):
-    """Screen, then compact its keep mask, escalating the capacity 4× on
-    overflow (capped at B, where compaction cannot overflow) at most
-    ``max_doublings`` times: ``(idx, valid, overflow)`` of the last step.
-
-    ``screen()`` enqueues the screen and returns the (Q, B) keep mask.
-    Each step ends in the ``device_get(overflow)`` the loop needs anyway,
-    so its span holds its device work: ``repro.engine.screen`` the screen
-    (and any radius tightening) with the first compaction,
-    ``repro.engine.escalate`` each further one."""
-    with span("repro.engine.screen", cap=cap):
-        keep = screen()
-        idx, valid, overflow = _compact_mask(keep, cap)
-        done = cap >= B or not bool(jax.device_get(overflow).any())
+def _ladder_cap(cap: int, B: int, max_doublings: int, need: int) -> int:
+    """The capacity ladder's stop: the first of ``cap``, 4·cap, 16·cap, …
+    (capped at B, where compaction cannot overflow; at most
+    ``max_doublings`` steps up) that holds ``need`` survivors, else its
+    last rung, where the rows above it overflow."""
     for _ in range(max_doublings):
-        if done:
+        if cap >= B or need <= cap:
             break
         cap = min(B, cap * 4)
-        with span("repro.engine.escalate", cap=cap):
-            idx, valid, overflow = _compact_mask(keep, cap)
-            done = cap >= B or not bool(jax.device_get(overflow).any())
-    return idx, valid, overflow
+    return cap
+
+
+def _screen_and_compact(screen, cap: int, B: int, max_doublings: int):
+    """Screen, count the survivors, then compact once at the capacity the
+    4× ladder from ``cap`` stops at for the batch's largest count
+    (:func:`_ladder_cap`): ``(idx, valid, overflow)``, overflow only past
+    ``max_doublings`` steps.  Capacities stay rungs of the ladder, so a
+    batch builds the program an escalating loop would have ended on.
+
+    ``screen()`` enqueues the screen and returns the (Q, B) keep mask.
+    The span ``repro.engine.screen`` holds the screen (and any radius
+    tightening) up to the count's ``device_get``, then enqueues the
+    compaction; it carries ``survivors_max`` and the ``cap`` chosen."""
+    with span("repro.engine.screen") as sp:
+        words, prefix, need = _survivor_prefix(screen())
+        need = int(jax.device_get(need))
+        cap = _ladder_cap(cap, B, max_doublings, need)
+        sp.set(survivors_max=need, cap=cap)
+        return _compact_counted(words, prefix, cap)
 
 
 def _coerce_quant_options(options, legacy: dict):
@@ -1788,10 +1886,11 @@ def quantized_range_query(
     Screens on the quantized resident tier (widened bounds — no true
     answer can be excluded), compacts survivors, fetches ONLY those rows
     from the raw mmap tier, and exact-verifies them in the engine's diff²
-    form.  Capacity escalates 4× on overflow (capped at B, where
-    compaction cannot overflow), so the certificate is always True on
-    return.  Returns ``(idx (Q, C), answer (Q, C), d2 (Q, C), exact (Q,))``
-    — set-identical to :func:`range_query` / ``range_query_compact``
+    form.  The capacity is the rung of the 4× ladder from ``capacity``
+    (capped at B, where compaction cannot overflow) that holds the
+    batch's largest survivor count, counted before the one compaction,
+    so the certificate is always True on return.  Returns ``(idx (Q, C),
+    answer (Q, C), d2 (Q, C), exact (Q,))`` — set-identical to :func:`range_query` / ``range_query_compact``
     (property-tested in tests/test_quantized.py).  Knobs ride in
     ``options`` (:class:`SearchOptions`); the old ``capacity=`` /
     ``backend=`` / ``max_doublings=`` kwargs shim through with a
@@ -1806,7 +1905,7 @@ def quantized_range_query(
     Q, B = qr.q.shape[0], tindex.size
     eps = _eps_qcol(epsilon, Q)
     cap = min(B, 64 if capacity is None else max(1, int(capacity)))
-    idx, valid, overflow = _compact_escalating(
+    idx, valid, overflow = _screen_and_compact(
         lambda: _quantized_screen_backend(tindex, qr, eps, opts.backend)[0],
         cap, B, max_doublings)
     d2 = _verify_tier(tindex.raw, idx, qr.q, valid, opts)
@@ -1859,8 +1958,9 @@ def quantized_knn_query(
     (:func:`_tighten_tiered_keep`), then exact-verifies the surviving
     candidates from the raw tier and
     takes their top-k (ties to the lowest index, the engine-wide order).
-    Capacity escalates on overflow up to B, so ``exact`` is always True
-    on return: the answer provably equals brute force.  Knobs ride in
+    The capacity is the 4× ladder's rung that holds the largest survivor
+    count (up to B), so ``exact`` is always True on return: the answer
+    provably equals brute force.  Knobs ride in
     ``options`` (:class:`SearchOptions`); old kwargs shim through with a
     :class:`DeprecationWarning`.
     """
@@ -1881,7 +1981,7 @@ def quantized_knn_query(
                                     jnp.ones((Q, 1), bool), k_eff)
 
     cap = min(B, max(4 * k_eff, 64) if capacity is None else int(capacity))
-    idx, valid, overflow = _compact_escalating(screen, max(cap, k_eff), B,
+    idx, valid, overflow = _screen_and_compact(screen, max(cap, k_eff), B,
                                                max_doublings)
     d2 = _verify_tier(tindex.raw, idx, qr.q, valid, opts)
     neg, pos = jax.lax.top_k(-d2, k_eff)                     # ascending d2
@@ -1901,7 +2001,8 @@ def quantized_mixed_query(
     caller's ε (the widening happens inside the screen), k-NN rows at
     their slacked seeded radius; one shared compaction + raw-tier exact
     verify serves both.  Returns ``(idx, answer, d2, overflow)`` with
-    ``overflow`` all-False after escalation — for k-NN rows ``answer``
+    ``overflow`` all-False (the capacity is chosen from the survivor
+    count, as in :func:`quantized_range_query`) — for k-NN rows ``answer``
     marks valid candidate slots (a verified superset of the true top-k),
     extracted per row via :func:`mixed_topk` exactly like the other
     serving backends.  Knobs ride in ``options``
@@ -1930,7 +2031,7 @@ def quantized_mixed_query(
         return keep
 
     cap = min(B, max(4 * k_eff, 64) if capacity is None else int(capacity))
-    idx, valid, overflow = _compact_escalating(screen, max(cap, k_eff), B,
+    idx, valid, overflow = _screen_and_compact(screen, max(cap, k_eff), B,
                                                max_doublings)
     d2 = _verify_tier(tindex.raw, idx, qr.q, valid, opts)
     answer = jnp.where(knn_col, valid, valid & (d2 <= eps_req * eps_req))

@@ -302,8 +302,9 @@ class _QuantizedBackend:
     device-resident, the full-precision rows stay in the mmap tier and
     are gathered only for the survivors' exact verify.
 
-    Capacity escalation lives inside ``engine.quantized_mixed_query``
-    (auto-escalating compaction), so the dispatch here is a single call.
+    The capacity choice lives inside ``engine.quantized_mixed_query``
+    (counted before its one compaction), so the dispatch here is a
+    single call.
     Answers are set-identical to the full-precision backends — the
     widened screen is a provable superset and the verify is exact
     (tested in tests/test_serve.py's quantized cases).
@@ -314,7 +315,6 @@ class _QuantizedBackend:
         self.cfg = cfg
         # The screen's engine (the exact verify is XLA either way).
         self.backend = stack_backend(tindex.dev, resolve_backend(cfg.backend))
-        self._cap: Optional[int] = None
         self.stats: Optional[StatsTracker] = None   # set by SearchService
 
     @property
@@ -364,12 +364,11 @@ class _QuantizedBackend:
                                                        DEFAULT_STACK)))
             eps_j = jnp.asarray(eps, jnp.float32)
             knn_j = jnp.asarray(is_knn)
-        cap = self._cap or self.cfg.capacity0 or max(4 * k, 64)
+        cap = self.cfg.capacity0 or max(4 * k, 64)
         idx, answer, d2, overflow = quantized_mixed_query(
             self.tindex, qr, eps_j, knn_j, k,
             options=SearchOptions(backend=self.backend, capacity=cap,
                                   verify_prefetch=self.cfg.verify_prefetch))
-        self._cap = max(cap, self._cap or 0)
         if self.stats is not None:
             bad = int(np.asarray(overflow).sum())
             total = int(np.asarray(overflow).size)

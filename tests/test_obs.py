@@ -526,7 +526,7 @@ def test_tiered_spans_show_escalation_and_gather(hidx, tmp_path):
         normalize=False)
     qs = make_queries(db, 2, seed=22)
     # ε far beyond every row: the screen keeps the whole database, so the
-    # first capacity overflows and the compaction escalates up to B.
+    # survivor count is B and the capacity is the ladder's rung at B.
     reqs = [svc.submit_range(qs[0], 1e3), svc.submit_knn(qs[1], 3)]
     run, outs = _serve_one_batch(svc, reqs)
     spans = _profiled(run, tmp_path)
@@ -535,17 +535,17 @@ def test_tiered_spans_show_escalation_and_gather(hidx, tmp_path):
     qb, slots = batch[3]["qb"], outs[0][0].shape[-1]
     (seed,) = _named(spans, "repro.engine.seed")
     (screen,) = _named(spans, "repro.engine.screen")
-    escalate = _named(spans, "repro.engine.escalate")
-    assert escalate
-    assert screen[3]["cap"] == max(4, batch[3]["kb"])
-    caps = [screen[3]["cap"]] + [sp[3]["cap"] for sp in escalate]
-    assert all(b == min(B, 4 * a) for a, b in zip(caps, caps[1:]))
-    assert caps[-1] == slots == B
+    assert not _named(spans, "repro.engine.escalate")
+    assert screen[3]["survivors_max"] == B
+    ladder = [max(4, batch[3]["kb"])]
+    while ladder[-1] < screen[3]["survivors_max"]:
+        ladder.append(min(B, 4 * ladder[-1]))
+    assert screen[3]["cap"] == ladder[-1] == slots == B
     (gather,) = _named(spans, "repro.engine.gather")
     assert gather[3]["rows"] == qb * slots
     assert gather[3]["bytes"] == qb * slots * N * 4
     (verify,) = _named(spans, "repro.engine.verify")
-    order = [seed, screen, *escalate, gather, verify]
+    order = [seed, screen, gather, verify]
     assert all(_inside(sp, device) for sp in order)
     assert all(a[2] <= b[1] for a, b in zip(order, order[1:]))
 

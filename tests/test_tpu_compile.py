@@ -1,4 +1,5 @@
-"""The main-path Pallas kernels compile for a TPU v5e at serving widths.
+"""The main-path Pallas kernels, and the tiered screen's sort-free
+selection, compile for a TPU v5e at serving widths.
 
 Nothing runs: each test lowers a kernel with ``interpret=False`` against a
 described (not attached) ``v5e:2x2`` topology and compiles it with the TPU
@@ -9,7 +10,9 @@ kernel may use.  The topology is described inside a fixture, never while a
 module is imported, and the persistent compilation cache is off around the
 compiles (a described chip's entries cannot be read back).
 """
+import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -113,3 +116,36 @@ def test_fused_quant_range_compiles(one_chip, mode):
     _compile(lambda d, *a: fq.fused_quant_range_pallas(
         d, *a, block_q=bq, block_b=bb, interpret=False),
         qdev, *_query_side(one_chip))
+
+
+def _sorts(compiled) -> list:
+    return re.findall(r"\bsort\(", compiled.as_text())
+
+
+@pytest.mark.parametrize("part", ["count", "compact", "kth"])
+def test_tiered_selection_compiles_without_sort(one_chip, part):
+    """The tiered screen's selection epilogue — survivor count, compaction
+    at a capacity, k-th smallest screen bound — compiles for the chip at
+    a (Q, B) mask with no sort over the database axis in it."""
+    if part == "count":
+        fn, args = engine._survivor_prefix, (_spec(one_chip, (Q, B),
+                                                   jnp.bool_),)
+    elif part == "compact":
+        fn = functools.partial(engine._compact_counted, capacity=1024)
+        args = (_spec(one_chip, (Q, B // 32), jnp.uint32),
+                _spec(one_chip, (Q, B // 32), jnp.int32))
+    else:
+        fn = functools.partial(engine._kth_smallest_bisect, k=K)
+        args = (_spec(one_chip, (Q, B)),)
+    assert not _sorts(jax.jit(fn).lower(*args).compile())
+
+
+def test_top_k_compaction_compiles_to_a_sort(one_chip):
+    """The check above can see a sort: the ``lax.top_k`` form it replaced
+    compiles to one."""
+    def top_k_compaction(keep):
+        keys = jnp.where(keep, B - jnp.arange(B, dtype=jnp.int32), 0)
+        return jax.lax.top_k(keys, 1024)
+
+    assert _sorts(jax.jit(top_k_compaction).lower(
+        _spec(one_chip, (Q, B), jnp.bool_)).compile())
